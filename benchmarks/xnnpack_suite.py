@@ -77,6 +77,20 @@ def workloads():
     ]
 
 
+def array_fn(fn, args, kw):
+    """``fn`` as a function of the array arguments among ``args`` (the
+    rest are closed over), and those arrays — what ``jax.jit`` and
+    ``jax.eval_shape`` take."""
+    is_arr = [hasattr(a, "shape") for a in args]
+
+    def f(*arrs):
+        it = iter(arrs)
+        return fn(*[next(it) if ok else a for a, ok in zip(args, is_arr)],
+                  **kw)
+
+    return f, [a for a, ok in zip(args, is_arr) if ok]
+
+
 def run_target(target, check=False):
     """One Figure-2 column: per-op baseline vs selector-chosen lowering
     under ``target``, straight from the selection engine's cost models.
@@ -159,17 +173,8 @@ def run_tpu(target="tpu-v5e"):
             low_v = REGISTRY.select(opname, *args, policy="vector", **kw)
             base_instrs = trace.jaxpr_vector_instrs(
                 low_v.fn, *args, scalarize=False, union_overhead=False, **kw)
-            is_arr = [hasattr(a, "shape") for a in args]
-            arr_args = [a for a, ok in zip(args, is_arr) if ok]
-
-            def _fn(*traced, _f=low_v.fn, _is=tuple(is_arr),
-                    _args=args, _kw=kw):
-                it = iter(traced)
-                full = [next(it) if ok else a
-                        for a, ok in zip(_args, _is)]
-                return _f(*full, **_kw)
-
-            out = jax.eval_shape(_fn, *arr_args)
+            f, arrs = array_fn(low_v.fn, args, kw)
+            out = jax.eval_shape(f, *arrs)
             base_bytes = trace.jaxpr_hbm_bytes(low_v.fn, *args, **kw)
             cust_bytes = _kernel_io_bytes(opname, args, kw, out)
             rows.append({

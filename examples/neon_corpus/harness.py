@@ -255,6 +255,62 @@ def _ref_qs8_gemm(m, k, a, b, c):
     return out
 
 
+# -- conformance check ----------------------------------------------------------
+
+# float ULP budgets: the executors agree bitwise per-op, but XLA's
+# whole-kernel fusion re-associates mul/add chains; polynomial kernels
+# (rational tanh/sigmoid, Newton rsqrt, dot accumulation) compound that
+# over the chain, mirrored by their harness rtol.
+_F32_EPS = float(np.finfo(np.float32).eps)
+
+
+def ulp_budget(case: Case) -> int:
+    return max(4, int(2 * case.rtol / _F32_EPS))
+
+
+def ulp_distance(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    def ordered(x):
+        i = x.view(np.int32).astype(np.int64)
+        return np.where(i < 0, -(i & 0x7FFFFFFF), i)
+
+    return np.abs(ordered(a) - ordered(b))
+
+
+def assert_conforms(got, want, case: Case, label: str) -> None:
+    """Raise AssertionError unless ``got`` conforms to the reference:
+    bitwise for integer outputs, within the case's ULP budget (or its
+    absolute tolerance) for float outputs."""
+    got = got if isinstance(got, tuple) else (got,)
+    want = want if isinstance(want, tuple) else (want,)
+    if len(got) != len(want):
+        raise AssertionError(f"{label}: {len(got)} outputs, want "
+                             f"{len(want)}")
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        if g.shape != w.shape or g.dtype != w.dtype:
+            raise AssertionError(f"{label}: shape/dtype {g.shape}/{g.dtype} "
+                                 f"vs {w.shape}/{w.dtype}")
+        if np.issubdtype(w.dtype, np.integer):
+            np.testing.assert_array_equal(
+                g, w, err_msg=f"{label}: integer kernel must match "
+                              f"bitwise")
+        else:
+            # ULP budget, with an absolute-tolerance escape: XLA fuses
+            # mul+add chains into FMAs, so a catastrophically-cancelling
+            # lane (|result| << |operands|) can sit many ULP-of-result
+            # from the two-step reference while the absolute error stays
+            # at one ULP of the *operands* — that is conforming.
+            budget = ulp_budget(case)
+            ulp = ulp_distance(g.astype(np.float32), w.astype(np.float32))
+            ok = (ulp <= budget) | \
+                (np.abs(g.astype(np.float64) - w.astype(np.float64))
+                 <= max(case.atol, 1e-6))
+            if not np.all(ok):
+                raise AssertionError(
+                    f"{label}: float divergence of {int(ulp.max())} ULP "
+                    f"(budget {budget}) beyond atol {max(case.atol, 1e-6)}")
+
+
 # -- the corpus ---------------------------------------------------------------
 
 def cases(n: int = 64, tail_n: int = 67, seed: int = 0) -> Sequence[Case]:

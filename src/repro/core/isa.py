@@ -1053,6 +1053,10 @@ def vmovn(a, dtype):
 def _sat_narrow(a, dtype):
     dst = jnp.dtype(dtype)
     info = jnp.iinfo(dst)
+    # clamp sub-32-bit lanes in 32 bits: XLA:TPU clamps an int16
+    # arithmetic right shift's negative lanes to the upper bound
+    if a.dtype.itemsize < 4:
+        a = a.astype(jnp.int32)
     return jnp.clip(a, info.min, info.max).astype(dst)
 
 

@@ -76,7 +76,11 @@ class Lowering:
             return True
         try:
             return bool(self.supports(*args, **kw))
-        except Exception:
+        except Exception as e:
+            from . import trace  # local import to avoid cycle at init
+            trace.warn_cost_model(self, e, "treating the lowering as "
+                                  "invalid for these operands",
+                                  what="supports predicate")
             return False
 
 
@@ -148,7 +152,10 @@ class _Registry:
     def __init__(self, cache_capacity: int = DEFAULT_CACHE_CAPACITY):
         self._ops: Dict[str, Dict[str, Lowering]] = {}
         self._tls = threading.local()
-        self._default = "pallas"
+        # resolved on first use, not at import: asking JAX for its
+        # backend initialises it, and a process that imports the models
+        # must not take the chip before it means to
+        self._default: Optional[str] = None
         # LRU: key -> (lowering, evaluated cost) — see _select_entry.
         # The lock covers every cache read/write: the hit path mutates
         # recency order (move_to_end), so unlike a plain-dict memo a
@@ -187,18 +194,22 @@ class _Registry:
     # -- policy (a *cap* on the candidate tier set) -------------------------
     @property
     def policy(self) -> str:
-        return getattr(self._tls, "policy", self._default)
-
-    def set_default_policy(self, policy: str) -> None:
-        if policy not in TIERS:
-            raise ValueError(f"unknown policy {policy!r}")
-        self._default = policy
+        pol = getattr(self._tls, "policy", None)
+        if pol is not None:
+            return pol
+        if self._default is None:
+            # customized kernels on TPU, vector tier elsewhere (SIMDe's
+            # "native if available" rule)
+            import jax
+            self._default = ("pallas" if jax.default_backend() == "tpu"
+                             else "vector")
+        return self._default
 
     @contextlib.contextmanager
     def use_policy(self, policy: str):
         if policy not in TIERS:
             raise ValueError(f"unknown policy {policy!r}")
-        prev = self.policy
+        prev = getattr(self._tls, "policy", None)
         self._tls.policy = policy
         try:
             yield

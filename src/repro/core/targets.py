@@ -203,6 +203,22 @@ for _bits in (64, 128, 256, 512, 1024):
 # The paper's evaluation family (Figure 2 sweeps these widths).
 RVV_FAMILY = ("rvv-128", "rvv-256", "rvv-512", "rvv-1024")
 
+# ``jax.Device.device_kind`` of each chip this repository runs on -> the
+# Target describing it.  A kind missing here is an error, never a default.
+DEVICE_KINDS = {
+    "TPU v5 lite": "tpu-v5e",
+    "TPU v6 lite": "tpu-v6",
+}
+
+
+def device_target(device_kind: str) -> "Target":
+    """The Target for a device as JAX reports it (``device_kind``)."""
+    try:
+        return TARGETS[DEVICE_KINDS[device_kind]]
+    except KeyError:
+        raise KeyError(f"no target for device kind {device_kind!r}; "
+                       f"known: {sorted(DEVICE_KINDS)}") from None
+
 
 def get_target(t: Union[str, Target]) -> Target:
     if isinstance(t, Target):

@@ -106,15 +106,17 @@ def calibrated_cost(op: str, cost: Optional[int]) -> Optional[int]:
     return max(1, int(round(cost * f))) if cost > 0 else 0
 
 
-def warn_cost_model(lowering, exc, consequence: str) -> None:
-    """Log a broken cost model once per (op, tier) — it is a real defect
-    in the selection data, not something to silently mask."""
-    key = (lowering.op, lowering.tier)
+def warn_cost_model(lowering, exc, consequence: str,
+                    what: str = "cost model") -> None:
+    """Log a broken cost model (or ``supports`` predicate) once per
+    (op, tier) — it is a real defect in the selection data, not
+    something to silently mask."""
+    key = (lowering.op, lowering.tier, what)
     if key not in _cost_warned:
         _cost_warned.add(key)
-        log.warning("cost model for %s/%s raised %r; %s (fix the model — "
+        log.warning("%s for %s/%s raised %r; %s (fix the model — "
                     "selection quality depends on it)",
-                    lowering.op, lowering.tier, exc, consequence)
+                    what, lowering.op, lowering.tier, exc, consequence)
 
 
 def record(lowering, *args, cost=None, **kw) -> None:
@@ -263,7 +265,7 @@ def traced_cost(fn, *, union_overhead: bool = True,
 SCALARIZED_PRIMS = set(PRIM_SCALAR_COST)
 _FREE_PRIMS = {"reshape", "broadcast_in_dim", "squeeze", "convert_element_type",
                "copy", "stop_gradient", "slice", "transpose", "bitcast_convert_type"}
-_CTRL_PRIMS = ("pjit", "scan", "while", "cond", "custom_jvp_call",
+_CTRL_PRIMS = ("jit", "scan", "while", "cond", "custom_jvp_call",
                "custom_vjp_call", "remat", "checkpoint")
 
 

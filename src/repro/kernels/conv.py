@@ -25,14 +25,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _pltpu_compat  # noqa: F401  (CompilerParams rename shim)
-
 from repro.core.vtypes import round_up, vmem_fit
 from repro.core import masks
 
 
 def _conv_body(x_ref, w_ref, b_ref, o_ref, *, kh, kw, sh, sw, has_bias,
                out_dtype):
+    # f32 inputs take the MXU's full-precision passes (the default
+    # rounds them to bf16); bf16 inputs are exact in one pass
+    prec = jax.lax.Precision.HIGHEST if x_ref.dtype == jnp.float32 else None
     x = x_ref[...].astype(jnp.float32)            # (1, H, W, Ci)
     w = w_ref[...].astype(jnp.float32)            # (kh, kw, Ci, Co)
     _, ih, iw, ci = x.shape
@@ -47,6 +48,7 @@ def _conv_body(x_ref, w_ref, b_ref, o_ref, *, kh, kw, sh, sw, has_bias,
                                  j + sw * (ow - 1) + 1, ci),
                                 (1, sh, sw, 1))   # (1, oh, ow, ci)
             acc += jnp.dot(tap.reshape(oh * ow, ci), w[i, j],
+                           precision=prec,
                            preferred_element_type=jnp.float32)
     if has_bias:
         acc = acc + b_ref[...].astype(jnp.float32)

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _pltpu_compat  # noqa: F401  (CompilerParams rename shim)
-
 from repro.core.targets import compile_target, current_target
 from repro.core.vtypes import round_up
 from repro.core import masks
@@ -231,6 +229,8 @@ def decode_attention(q, k, v, lengths, *, softcap=None, window=None,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, rq, dp), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), q_p, k_p, v_p)
     return out[:, :, :1, :d]

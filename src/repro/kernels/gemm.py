@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _pltpu_compat  # noqa: F401  (CompilerParams rename shim)
-
 from repro.core.targets import compile_target
 from repro.core.vtypes import round_up
 from repro.core import masks
@@ -36,7 +34,10 @@ def _gemm_kernel(a_ref, b_ref, bias_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
+    # f32 operands take the MXU's full-precision passes: by default it
+    # rounds them to bf16, which an f32 GEMM must not do
+    prec = jax.lax.Precision.HIGHEST if a_ref.dtype == jnp.float32 else None
+    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...], precision=prec,
                             preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)

@@ -17,25 +17,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _pltpu_compat  # noqa: F401  (CompilerParams rename shim)
-
 from repro.core.vtypes import round_up, vmem_fit
 from repro.core import masks
 
 BP = 8  # pixels per block (sublane-aligned)
 
 
-def _ibilinear_body(iy_ref, ix_ref, wy_ref, wx_ref, img_ref, o_ref, *, bp):
+def _ibilinear_body(iy_ref, ix_ref, wy_ref, wx_ref, img_ref, o_ref, *, bp, w):
     blk = pl.program_id(0)
     for p in range(bp):  # static unroll; each p is one output pixel
-        y = iy_ref[blk * bp + p]
-        x = ix_ref[blk * bp + p]
-        corners = img_ref[pl.ds(y, 2), pl.ds(x, 2), :].astype(jnp.float32)
-        wy = wy_ref[p].astype(jnp.float32)
-        wx = wx_ref[p].astype(jnp.float32)
-        top = corners[0, 0] * (1 - wx) + corners[0, 1] * wx
-        bot = corners[1, 0] * (1 - wx) + corners[1, 1] * wx
-        o_ref[p, :] = (top * (1 - wy) + bot * wy).astype(o_ref.dtype)
+        # top-left corner's row in the (H*W, C) image; the four corners
+        # are single-row loads with the channels on the lanes
+        r = iy_ref[blk * bp + p] * w + ix_ref[blk * bp + p]
+
+        def corner(off):
+            return img_ref[pl.ds(r + off, 1), :].astype(jnp.float32)
+
+        wy = wy_ref[p:p + 1, :].astype(jnp.float32)   # (1, 1)
+        wx = wx_ref[p:p + 1, :].astype(jnp.float32)
+        top = corner(0) * (1 - wx) + corner(1) * wx
+        bot = corner(w) * (1 - wx) + corner(w + 1) * wx
+        o_ref[p:p + 1, :] = (top * (1 - wy) + bot * wy).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -46,24 +48,26 @@ def ibilinear(img, iy, ix, wy, wx, *, interpret=False):
     pp = round_up(p, BP)
     iy_p = masks.pad_to(iy, (pp,))
     ix_p = masks.pad_to(ix, (pp,))
-    wy_p = masks.pad_to(wy, (pp,))
-    wx_p = masks.pad_to(wx, (pp,))
+    # weights as (P, 1) columns: a rank-1 (BP,) block is not a legal
+    # TPU tile
+    wy_p = masks.pad_to(wy, (pp,)).reshape(pp, 1)
+    wx_p = masks.pad_to(wx, (pp,)).reshape(pp, 1)
     grid = (pp // BP,)
     out = pl.pallas_call(
-        functools.partial(_ibilinear_body, bp=BP),
+        functools.partial(_ibilinear_body, bp=BP, w=w),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((BP,), lambda i, iy_r, ix_r: (i,)),
-                pl.BlockSpec((BP,), lambda i, iy_r, ix_r: (i,)),
-                pl.BlockSpec((h, w, c), lambda i, iy_r, ix_r: (0, 0, 0)),
+                pl.BlockSpec((BP, 1), lambda i, iy_r, ix_r: (i, 0)),
+                pl.BlockSpec((BP, 1), lambda i, iy_r, ix_r: (i, 0)),
+                pl.BlockSpec((h * w, c), lambda i, iy_r, ix_r: (0, 0)),
             ],
             out_specs=pl.BlockSpec((BP, c), lambda i, iy_r, ix_r: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((pp, c), img.dtype),
         interpret=interpret,
-    )(iy_p, ix_p, wy_p, wx_p, img)
+    )(iy_p, ix_p, wy_p, wx_p, img.reshape(h * w, c))
     return out[:p]
 
 

@@ -19,7 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core import registry, trace
+from repro.core import trace
 from repro.core.registry import register, dispatch
 from . import conv as _conv
 from . import elementwise as _ew
@@ -33,10 +33,6 @@ from . import ssd as _ssd
 
 def _interp() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def default_policy() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "vector"
 
 
 # ---------------------------------------------------------------------------
@@ -288,7 +284,7 @@ def _attn_supports(q, k, v, causal=True, window=None, softcap=None,
 
 
 @register("attention", "pallas", supports=_attn_supports,
-          cost=lambda q, k, v, causal=True, **kw: _fa.cost(
+          cost=lambda q, k, v, causal=True, *_, **kw: _fa.cost(
               q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
               v.transpose(0, 2, 1, 3), causal=causal),
           doc="online-softmax flash attention, VMEM-resident stats")
@@ -342,8 +338,8 @@ def _dec_ref(q, k, v, lengths, window, softcap, scale):
 
 
 @register("decode_attention", "pallas",
-          supports=lambda q, k, v, lengths, **kw: q.shape[1] == 1,
-          cost=lambda q, k, v, lengths, **kw: _fa.cost(
+          supports=lambda q, k, v, lengths, *_, **kw: q.shape[1] == 1,
+          cost=lambda q, k, v, lengths, *_, **kw: _fa.cost(
               q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
               v.transpose(0, 2, 1, 3), causal=False),
           doc="flash-decode with dynamic valid length (scalar prefetch)")
@@ -384,8 +380,3 @@ def _ssd_pallas(x, dt, A, B, C, D=None, *, chunk=128):
 
 def ssd(x, dt, A, B, C, D=None, *, chunk=128, policy=None, target=None):
     return dispatch("ssd", x, dt, A, B, C, D, policy=policy, target=target)
-
-
-# default policy: customized kernels on TPU, vector tier elsewhere (the
-# same "native if available" rule as SIMDe's ladder).
-registry.REGISTRY.set_default_policy(default_policy())

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _pltpu_compat  # noqa: F401  (CompilerParams rename shim)
-
 from repro.core.vtypes import round_up
 from repro.core import masks
 

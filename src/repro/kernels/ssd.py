@@ -24,15 +24,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _pltpu_compat  # noqa: F401  (CompilerParams rename shim)
-
 from repro.core.targets import compile_target, current_target
 from repro.core.vtypes import round_up
 from repro.core import masks
 
 
-def _ssd_body(a_ref, x_ref, dt_ref, b_ref, c_ref, o_ref, state_ref, *,
-              nchunks, out_dtype):
+def _ssd_body(a_ref, x_ref, dt_ref, dtr_ref, b_ref, c_ref, o_ref, state_ref,
+              *, nchunks, out_dtype):
     bh, ci = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ci == 0)
@@ -41,27 +39,36 @@ def _ssd_body(a_ref, x_ref, dt_ref, b_ref, c_ref, o_ref, state_ref, *,
 
     a = a_ref[bh]                                  # scalar A (negative)
     x = x_ref[0].astype(jnp.float32)               # (L, p)
-    dt = dt_ref[0].astype(jnp.float32)             # (L, 1) column layout
+    dt_c = dt_ref[0].astype(jnp.float32)           # (L, 1) column layout
+    dt_r = dtr_ref[0].astype(jnp.float32)          # (1, L) row layout
     bm = b_ref[0].astype(jnp.float32)              # (L, n)
     cm = c_ref[0].astype(jnp.float32)              # (L, n)
     L = x.shape[0]
+    t = (((1,), (1,)), ((), ()))                   # contract both minor dims
 
-    la = jnp.cumsum(dt[:, 0] * a)                  # (L,), log-decay inclusive
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    causal = row >= col
+    # inclusive log-decay la_i = A * sum_{j<=i} dt_j, as a column and as a
+    # row: masked sums, because the TPU lowering has no cumsum
+    la_c = a * jnp.sum(jnp.where(causal, dt_r, 0.0), axis=1, keepdims=True)
+    la_r = a * jnp.sum(jnp.where(row <= col, dt_c, 0.0), axis=0,
+                       keepdims=True)
     # inter-chunk: y_i += exp(la_i) * C_i . S
-    y_inter = jnp.exp(la)[:, None] * jnp.dot(
-        cm, state_ref[...].T, preferred_element_type=jnp.float32)  # (L, p)
-    # intra-chunk: masked decay kernel
-    diff = la[:, None] - la[None, :]               # la_i - la_j
-    causal = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    w = jnp.where(causal, jnp.exp(diff), 0.0) * dt[:, 0][None, :]
-    g = jnp.dot(cm, bm.T, preferred_element_type=jnp.float32)      # (L, L)
+    y_inter = jnp.exp(la_c) * jax.lax.dot_general(
+        cm, state_ref[...], t, preferred_element_type=jnp.float32)  # (L, p)
+    # intra-chunk: masked decay kernel exp(la_i - la_j) dt_j
+    w = jnp.where(causal, jnp.exp(la_c - la_r), 0.0) * dt_r
+    g = jax.lax.dot_general(cm, bm, t,
+                            preferred_element_type=jnp.float32)     # (L, L)
     y_intra = jnp.dot(g * w, x, preferred_element_type=jnp.float32)
     o_ref[0] = (y_inter + y_intra).astype(out_dtype)
     # state update: S <- exp(la_L) S + sum_j exp(la_L - la_j) dt_j x_j (x) B_j
-    wj = jnp.exp(la[L - 1] - la) * dt[:, 0]        # (L,)
-    state_ref[...] = jnp.exp(la[L - 1]) * state_ref[...] + jnp.dot(
-        (x * wj[:, None]).T, bm, preferred_element_type=jnp.float32)
+    la_last = a * jnp.sum(dt_c, axis=0, keepdims=True)             # (1, 1)
+    wj = jnp.exp(la_last - la_c) * dt_c                            # (L, 1)
+    state_ref[...] = jnp.exp(la_last) * state_ref[...] + jax.lax.dot_general(
+        x * wj, bm, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -78,6 +85,7 @@ def ssd(x, dt, A, B, C, D=None, *, chunk=128, interpret=False):
                       (b * h, sp, p))
     dtt = masks.pad_to(dt.transpose(0, 2, 1).reshape(b * h, s, 1),
                        (b * h, sp, 1))            # zero dt => no-op steps
+    dtr = dtt.reshape(b * h, 1, sp)
     Bh = jnp.repeat(B, rep, axis=2).transpose(0, 2, 1, 3).reshape(b * h, s, n)
     Ch = jnp.repeat(C, rep, axis=2).transpose(0, 2, 1, 3).reshape(b * h, s, n)
     Bh = masks.pad_to(Bh, (b * h, sp, n))
@@ -92,6 +100,7 @@ def ssd(x, dt, A, B, C, D=None, *, chunk=128, interpret=False):
             in_specs=[
                 pl.BlockSpec((1, L, p), lambda i, c, ar: (i, c, 0)),
                 pl.BlockSpec((1, L, 1), lambda i, c, ar: (i, c, 0)),
+                pl.BlockSpec((1, 1, L), lambda i, c, ar: (i, 0, c)),
                 pl.BlockSpec((1, L, n), lambda i, c, ar: (i, c, 0)),
                 pl.BlockSpec((1, L, n), lambda i, c, ar: (i, c, 0)),
             ],
@@ -102,7 +111,7 @@ def ssd(x, dt, A, B, C, D=None, *, chunk=128, interpret=False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(Ab, xt, dtt, Bh, Ch)
+    )(Ab, xt, dtt, dtr, Bh, Ch)
     y = out[:, :s].reshape(b, h, s, p).transpose(0, 2, 1, 3)
     if D is not None:
         y = y + (D[None, None, :, None] * x.astype(jnp.float32)).astype(y.dtype)

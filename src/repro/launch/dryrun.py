@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell the appropriate step function (train_step for train shapes,
@@ -16,6 +13,7 @@ shardings and lowered against ShapeDtypeStruct stand-ins — no allocation.
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -32,7 +30,8 @@ from repro.models import model as M
 from repro.models import sharding as Sh
 from repro.optim import adamw
 from repro.serve.engine import make_prefill_step, make_serve_step
-from repro.train.loop import TrainConfig, loss_fn, make_train_step
+from repro.train.loop import (TrainConfig, loss_fn, make_train_step,
+                              opt_state_pspecs)
 
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")
@@ -104,10 +103,7 @@ def lower_cell(arch: str, shape_name: str, mesh):
     if shape.kind == "train":
         tcfg = TrainConfig(accum=accum_for(cfg, shape))
         opt_sds = jax.eval_shape(adamw.init, params_sds)
-        ospecs = {"m": Sh.opt_pspecs(params_sds, cfg, mesh),
-                  "v": Sh.opt_pspecs(params_sds, cfg, mesh),
-                  "master": Sh.opt_pspecs(params_sds, cfg, mesh),
-                  "step": P()}
+        ospecs = opt_state_pspecs(params_sds, cfg, mesh)
         step = make_train_step(cfg, tcfg, mesh)
         fn = lambda p, o, batch: step(p, o, None, batch)[:2]
         jfn = jax.jit(fn,
@@ -165,8 +161,6 @@ def lower_cell(arch: str, shape_name: str, mesh):
 def analyze(lowered, compiled):
     from repro.launch import hlo_analysis
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax<0.5 returns [dict]
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     txt = compiled.as_text()
     hlo = hlo_analysis.analyze(txt)
@@ -228,6 +222,9 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
 
 def main():
+    # the production meshes need 512 host devices; set before the
+    # backend starts (nothing imported above touches it)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES)
     ap.add_argument("--shape", choices=tuple(SHAPES))

@@ -24,6 +24,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import get_config
     from repro.data.pipeline import extra_inputs
     from repro.models import model as M

@@ -48,6 +48,8 @@ def main():
     ap.add_argument("--host-id", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     if args.coordinator:
         os.environ.setdefault("XLA_FLAGS", TPU_OVERLAP_FLAGS)
         import jax

@@ -58,7 +58,6 @@ def linear_rp(w, x, cfg):
     xf = x.reshape(-1, x.shape[-1])
     if w.shape[0] % sizes["model"] or xf.shape[0] % nb:
         return linear(w, x)   # validity rule: shard_map needs exact tiles
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(xl, wl):
@@ -66,10 +65,10 @@ def linear_rp(w, x, cfg):
                       preferred_element_type=jnp.float32)
         return jax.lax.psum(out.astype(dt), "model")
 
-    out = shard_map(local, mesh,
-                    in_specs=(P(ba, "model"), P("model", None)),
-                    out_specs=P(ba, None),
-                    check_rep=False)(xf, w)
+    out = jax.shard_map(local, mesh=mesh,
+                        in_specs=(P(ba, "model"), P("model", None)),
+                        out_specs=P(ba, None),
+                        check_vma=False)(xf, w)
     return out.reshape(*lead, w.shape[-1])
 
 

@@ -107,7 +107,6 @@ def moe_apply(params, x, cfg):
     mesh = Sh.current_mesh()
 
     if mesh is not None and "model" in mesh.axis_names:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         ba = Sh.batch_axes(mesh)
         n_b = max(1, int(np.prod([dict(zip(mesh.axis_names,
@@ -124,13 +123,13 @@ def moe_apply(params, x, cfg):
                                   r * e_local, e_local)
             return jax.lax.psum(y, "model")
 
-        y = shard_map(
-            local, mesh,
+        y = jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(ba, None), P(ba, None), P(ba, None),
                       P("model", None, None), P("model", None, None),
                       P("model", None, None)),
             out_specs=P(ba, None),
-            check_rep=False,
+            check_vma=False,
         )(xt, gates.astype(jnp.float32), idx,
           params["we_g"], params["we_u"], params["we_d"])
     else:

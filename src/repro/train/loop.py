@@ -1,6 +1,6 @@
 """Training: step builder (grad-accum scan, sharded) + supervised loop.
 
-``make_train_step`` builds the pjit-able pure function; it is what the
+``make_train_step`` builds the jit-able pure function; it is what the
 multi-pod dry-run lowers.  ``train`` wires data, checkpointing, watchdog
 and restart supervision around it (the deployable driver).
 """
@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import checkpointer as ckpt
+from repro.core.registry import use_policy
 from repro.data.pipeline import SyntheticLM, extra_inputs
 from repro.kernels import ref
 from repro.models import model as M
@@ -71,7 +72,10 @@ def make_train_step(cfg, tcfg: TrainConfig, mesh=None):
             return (gsum, lsum + xent, asum + aux), None
 
         zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        with Sh.active_mesh(mesh):
+        # the Pallas lowerings define no backward pass (pallas_call has
+        # no transpose rule), so the differentiated forward is traced
+        # under the vector tier on every backend
+        with Sh.active_mesh(mesh), use_policy("vector"):
             (gsum, lsum, asum), _ = jax.lax.scan(
                 accum_body, (zeros, jnp.zeros(()), jnp.zeros(())),
                 jnp.arange(accum))
@@ -89,14 +93,17 @@ def make_train_step(cfg, tcfg: TrainConfig, mesh=None):
     return step
 
 
+def opt_state_pspecs(params_sds, cfg, mesh):
+    """PartitionSpecs of the AdamW state (``adamw.init``) on ``mesh``."""
+    spec = Sh.opt_pspecs(params_sds, cfg, mesh)
+    return {"m": spec, "v": spec, "master": spec, "step": P()}
+
+
 def make_sharded_train_step(cfg, tcfg: TrainConfig, mesh, params_sds,
                             batch_sds):
     """jit the step with explicit in/out shardings for the mesh."""
     pspecs = Sh.param_pspecs(params_sds, cfg, mesh)
-    ospecs = {"m": Sh.opt_pspecs(params_sds, cfg, mesh),
-              "v": Sh.opt_pspecs(params_sds, cfg, mesh),
-              "master": Sh.opt_pspecs(params_sds, cfg, mesh),
-              "step": P()}
+    ospecs = opt_state_pspecs(params_sds, cfg, mesh)
     espec = Sh.opt_pspecs(params_sds, cfg, mesh) if tcfg.compress_grads \
         else None
     bspec = jax.tree.map(lambda _: Sh.token_spec(mesh), batch_sds)

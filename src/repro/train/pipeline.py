@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -59,8 +58,8 @@ def pipeline(stage_fn, stage_params, x_mb, mesh, *, axis: str = "pipe"):
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params,
                              is_leaf=lambda a: hasattr(a, "ndim")), P())
-    out = shard_map(ranked, mesh, in_specs=in_specs, out_specs=P(),
-                    check_rep=False)(stage_params, x_mb)
+    out = jax.shard_map(ranked, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                        check_vma=False)(stage_params, x_mb)
     # outputs for microbatch j emerge at tick j + s - 1
     return out[s - 1:]
 

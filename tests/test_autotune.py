@@ -191,7 +191,9 @@ d = c.get(k, "rvv-128")
 assert d is not None, "decision lost across process restart"
 print(json.dumps(d.to_dict()))
 """
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    # the child stays on the CPU: a TPU belongs to one process at a time
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", prog], env=env,
                        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr

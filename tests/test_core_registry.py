@@ -116,3 +116,45 @@ def test_isa_semantics_against_numpy():
                                   np.concatenate([a[3:], b[:3]]))
     rev = np.asarray(isa.vrev64(jnp.asarray(a)))
     np.testing.assert_array_equal(rev, a.reshape(-1, 2)[:, ::-1].reshape(-1))
+
+
+def test_import_leaves_backend_alone():
+    """Importing the model stack must not initialise a JAX backend (on a
+    TPU host that takes the chip); the default policy is resolved on the
+    first dispatch instead.  Run in a fresh CPU-only interpreter."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    prog = (
+        "from jax._src import xla_bridge as xb\n"
+        "import repro.models.model, repro.kernels.ops, repro.serve\n"
+        "assert not xb._backends, sorted(xb._backends)\n"
+        "from repro.core.registry import REGISTRY\n"
+        "print(REGISTRY.policy)\n")
+    r = subprocess.run([sys.executable, "-c", prog],
+                       env=dict(os.environ, PYTHONPATH=src,
+                                JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split() == ["vector"]
+
+
+@pytest.mark.parametrize("policy", ["generic", "vector", "pallas"])
+@pytest.mark.parametrize("op,dst,lo,hi", [("vqmovn", jnp.int8, -128, 127),
+                                          ("vqmovun", jnp.uint8, 0, 255)])
+def test_saturating_narrow_clamps_in_32_bits(policy, op, dst, lo, hi):
+    """XLA:TPU clamps the negative lanes of an int16 arithmetic right
+    shift to the upper bound (``clip(x >> 5, -128, 127)`` on int16);
+    every tier of the saturating narrows therefore clamps in int32."""
+    x = jnp.arange(-600, 600, 7, dtype=jnp.int16)
+    fn = getattr(isa, op)
+    with use_policy(policy):
+        text = str(jax.make_jaxpr(lambda a: fn(a, dst))(x))
+        got = np.asarray(fn(x, dst))
+    clamps = [ln for ln in text.splitlines()
+              if " = max " in ln or " = min " in ln]
+    assert clamps and all(":i32[" in ln for ln in clamps), clamps
+    np.testing.assert_array_equal(got, np.clip(np.asarray(x), lo, hi)
+                                  .astype(dst))

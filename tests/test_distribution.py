@@ -15,12 +15,12 @@ from repro.models import model as M
 from repro.models import sharding as Sh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ENV = {**os.environ, "PYTHONPATH": os.path.join(REPO, "src"),
-       "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
 
 
 def _run(code: str, devices: int = 8) -> str:
-    env = {**ENV,
+    # CPU-only child: a TPU belongs to one process at a time
+    env = {**os.environ, "PYTHONPATH": os.path.join(REPO, "src"),
+           "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}"}
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=900)
@@ -61,7 +61,8 @@ from repro.configs import get_config
 from repro.models import model as M, sharding as Sh
 from repro.train.loop import make_train_step, TrainConfig
 from repro.optim import adamw
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = get_config("gemma2-2b").reduced()
 params_sds = jax.eval_shape(lambda: M.init(cfg, jax.random.PRNGKey(0)))
 pspecs = Sh.param_pspecs(params_sds, cfg, mesh)
@@ -81,8 +82,6 @@ with mesh:
     lowered = jfn.lower(params_sds, opt_sds, batch)
 compiled = lowered.compile()
 ca = compiled.cost_analysis()
-if isinstance(ca, (list, tuple)):   # jax<0.5 returns [dict]
-    ca = ca[0] if ca else {}
 print(json.dumps({"ok": True,
                   "devices": len(jax.devices()),
                   "flops": ca.get("flops", 0)}))
@@ -110,11 +109,12 @@ from repro.models import model as M, sharding as Sh
 from repro.train.loop import make_train_step, TrainConfig
 from repro.optim import adamw
 from repro.data.pipeline import SyntheticLM
+from repro.launch.mesh import make_mesh
 cfg = get_config("gemma2-2b").reduced().replace(dtype="float32", n_layers=2)
 params = M.init(cfg, jax.random.PRNGKey(0))
 opt = adamw.init(params)
 batch = SyntheticLM(cfg.vocab_size, 16, 4).batch(0)
-mesh = jax.make_mesh((2, 1), ("data", "model"))
+mesh = make_mesh((2, 1), ("data", "model"))
 pspecs = Sh.param_pspecs(params, cfg, mesh)
 ospecs = {"m": Sh.opt_pspecs(params, cfg, mesh), "v": Sh.opt_pspecs(params, cfg, mesh),
           "master": Sh.opt_pspecs(params, cfg, mesh), "step": P()}
@@ -138,12 +138,12 @@ def test_compressed_psum_shard_map():
     """The int8 cross-pod collective: psum of quantized grads over 'pod'."""
     code = """
 import jax, jax.numpy as jnp, json, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.optim.compression import compressed_psum
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_mesh((8,), ("pod",))
 x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16) / 37.0
-f = shard_map(lambda v: compressed_psum(v[0], "pod")[None],
+f = jax.shard_map(lambda v: compressed_psum(v[0], "pod")[None],
               mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None))
 got = f(x)
 want = jnp.mean(x, axis=0)

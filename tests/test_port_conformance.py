@@ -43,17 +43,6 @@ from repro.port.interp import Machine  # noqa: E402
 
 CONFORMANCE_TARGETS = ("rvv-64", "rvv-128", "rvv-512", "rvv-1024")
 
-# float ULP budgets: the executors agree bitwise per-op, but XLA's
-# whole-kernel fusion re-associates mul/add chains; polynomial kernels
-# (rational tanh/sigmoid, Newton rsqrt, dot accumulation) compound that
-# over the chain, mirrored by their harness rtol.
-_F32_EPS = float(np.finfo(np.float32).eps)
-
-
-def _ulp_budget(case: harness.Case) -> int:
-    return max(4, int(2 * case.rtol / _F32_EPS))
-
-
 _KERNELS = [c.kernel for c in harness.cases()]
 # the new width-changing / struct-load surface this suite guards
 WIDENING_KERNELS = ("qs8_vaddl_requant_ukernel", "qs8_vmul_requant_ukernel",
@@ -95,44 +84,6 @@ def _lengths(kernel: str, target: str, step: int):
     return sorted({0, 1, step - 1, step, step + 1, rand_n})
 
 
-def _assert_conforms(got, want, case: harness.Case, label: str):
-    got = got if isinstance(got, tuple) else (got,)
-    want = want if isinstance(want, tuple) else (want,)
-    assert len(got) == len(want)
-    for g, w in zip(got, want):
-        g, w = np.asarray(g), np.asarray(w)
-        assert g.shape == w.shape and g.dtype == w.dtype, \
-            f"{label}: shape/dtype {g.shape}/{g.dtype} vs " \
-            f"{w.shape}/{w.dtype}"
-        if np.issubdtype(w.dtype, np.integer):
-            np.testing.assert_array_equal(
-                g, w, err_msg=f"{label}: integer kernel must match "
-                              f"bitwise")
-        else:
-            # ULP budget, with an absolute-tolerance escape: XLA fuses
-            # mul+add chains into FMAs, so a catastrophically-cancelling
-            # lane (|result| << |operands|) can sit many ULP-of-result
-            # from the two-step reference while the absolute error stays
-            # at one ULP of the *operands* — that is conforming.
-            budget = _ulp_budget(case)
-            ulp = _ulp_distance(g.astype(np.float32),
-                                w.astype(np.float32))
-            ok = (ulp <= budget) | \
-                (np.abs(g.astype(np.float64) - w.astype(np.float64))
-                 <= max(case.atol, 1e-6))
-            assert bool(np.all(ok)), \
-                f"{label}: float divergence of {int(ulp.max())} ULP " \
-                f"(budget {budget}) beyond atol {max(case.atol, 1e-6)}"
-
-
-def _ulp_distance(a: np.ndarray, b: np.ndarray) -> np.ndarray:
-    def ordered(x):
-        i = x.view(np.int32).astype(np.int64)
-        return np.where(i < 0, -(i & 0x7FFFFFFF), i)
-
-    return np.abs(ordered(a) - ordered(b))
-
-
 @pytest.fixture(scope="module")
 def kernels():
     return {name: _kernel_obj(name) for name in _KERNELS}
@@ -156,7 +107,7 @@ def test_interp_conformance(kernel, target, kernels):
         case = _case_for(kernel, n)
         args = _args_for(case, seed=1000 + i)
         got = k(*args, target=target)
-        _assert_conforms(got, case.reference(*args), case,
+        harness.assert_conforms(got, case.reference(*args), case,
                          f"{kernel}/{target}/n={n}/interp")
 
 
@@ -209,7 +160,7 @@ def test_compiled_conformance(kernel, target, kernels):
         want = case.reference(*args)
         for revec_mode in (False, True):
             got = k.compile(target=target, revec=revec_mode)(*args)
-            _assert_conforms(
+            harness.assert_conforms(
                 got, want, case,
                 f"{kernel}/{target}/n={n}/compiled+revec={revec_mode}")
 
@@ -244,9 +195,9 @@ def test_widened_strip_matches_narrow_port_all_tails(kernel, kernels):
         narrow = k(*args, target="rvv-128")
         wide = Machine(wide_fn, policy="pallas", target="rvv-1024").run(
             *args)
-        _assert_conforms(wide, case.reference(*args), case,
+        harness.assert_conforms(wide, case.reference(*args), case,
                          f"{kernel}/n={n}/widened")
-        _assert_conforms(wide, tuple(np.asarray(x) for x in narrow)
+        harness.assert_conforms(wide, tuple(np.asarray(x) for x in narrow)
                          if isinstance(narrow, tuple)
                          else np.asarray(narrow), case,
                          f"{kernel}/n={n}/widened-vs-narrow")
@@ -296,9 +247,9 @@ def test_offset_site_matches_narrow_port_all_tails(kernel, kernels):
         narrow = k(*args, target="rvv-128")
         wide = Machine(wide_fn, policy="pallas", target="rvv-1024").run(
             *args)
-        _assert_conforms(wide, case.reference(*args), case,
+        harness.assert_conforms(wide, case.reference(*args), case,
                          f"{kernel}/n={n}/offset-widened")
-        _assert_conforms(wide, tuple(np.asarray(x) for x in narrow)
+        harness.assert_conforms(wide, tuple(np.asarray(x) for x in narrow)
                          if isinstance(narrow, tuple)
                          else np.asarray(narrow), case,
                          f"{kernel}/n={n}/offset-widened-vs-narrow")
@@ -374,9 +325,9 @@ if HAS_HYPOTHESIS:
         narrow = np.asarray(k(*args, target="rvv-128"))
         wide = np.asarray(Machine(wide_fn, policy="pallas",
                                   target="rvv-512").run(*args))
-        _assert_conforms(wide, case.reference(*args), case,
+        harness.assert_conforms(wide, case.reference(*args), case,
                          f"{kernel}/n={n}/property")
-        _assert_conforms(wide, narrow, case,
+        harness.assert_conforms(wide, narrow, case,
                          f"{kernel}/n={n}/property-vs-narrow")
 
 
@@ -409,9 +360,9 @@ def test_eager_compile_conformance(kernel, kernels):
                 args = _args_for(case, seed=3000 + i)
                 want = case.reference(*args)
                 label = f"{kernel}/{target}/n={n}/revec={revec_mode}"
-                _assert_conforms(eager(*args), want, case,
+                harness.assert_conforms(eager(*args), want, case,
                                  label + "/eager")
-                _assert_conforms(jitted(*args), want, case,
+                harness.assert_conforms(jitted(*args), want, case,
                                  label + "/jitted")
 
 

@@ -32,6 +32,15 @@ def test_target_registry_families():
         targets.get_target("no-such-target")
 
 
+def test_device_kind_table():
+    """A device kind maps to its Target; an unknown kind is an error,
+    never a fallback to the default target."""
+    assert targets.device_target("TPU v5 lite").name == "tpu-v5e"
+    assert targets.device_target("TPU v6 lite").name == "tpu-v6"
+    with pytest.raises(KeyError, match="cpu"):
+        targets.device_target("cpu")
+
+
 def test_vla_width_rule():
     """Table 2: a fixed-width register maps iff vlen >= width."""
     rvv64 = targets.get_target("rvv-64")
@@ -316,7 +325,7 @@ def test_cost_models_accept_scalar_operands():
 def test_broken_cost_model_logs_once(caplog):
     bad = Lowering(op="__bad", tier="vector", fn=lambda x: x,
                    cost=lambda *a, **k: 1 / 0)
-    trace._cost_warned.discard(("__bad", "vector"))
+    trace._cost_warned.discard(("__bad", "vector", "cost model"))
     with caplog.at_level(logging.WARNING, logger="repro.core.trace"):
         with trace.count() as c:
             trace.record(bad, jnp.zeros(4))
@@ -324,6 +333,20 @@ def test_broken_cost_model_logs_once(caplog):
     warnings = [r for r in caplog.records if "__bad" in r.getMessage()]
     assert len(warnings) == 1       # logged once, not swallowed
     assert c["total"] == 0
+
+
+def test_broken_supports_predicate_logs_once(caplog):
+    """A raising ``supports`` predicate still marks the lowering invalid,
+    but says so once instead of dropping the tier without a word."""
+    bad = Lowering(op="__badpred", tier="pallas", fn=lambda x: x,
+                   supports=lambda x, extra: True)   # wrong arity
+    trace._cost_warned.discard(("__badpred", "pallas", "supports predicate"))
+    with caplog.at_level(logging.WARNING, logger="repro.core.trace"):
+        assert not bad.ok(jnp.zeros(4))
+        assert not bad.ok(jnp.zeros(4))
+    warnings = [r for r in caplog.records if "__badpred" in r.getMessage()]
+    assert len(warnings) == 1
+    assert "supports predicate" in warnings[0].getMessage()
 
 
 # ---------------------------------------------------------------------------

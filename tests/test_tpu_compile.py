@@ -1,0 +1,131 @@
+"""Compile the chip's kernels for a described TPU v5e, without a chip.
+
+Interpret mode (every other kernel test) accepts block shapes, slices
+and scratch sizes that the TPU compiler refuses.  These tests lower each
+Pallas kernel of the main paths with ``interpret=False`` and compile it
+for one chip of a described ``v5e:2x2`` topology, at the shapes the
+chip runs: the ten XNNPACK conversions at the Figure-2 workload shapes,
+flash and decode attention at gemma2-2b serving shapes, ssd at
+mamba2-1.3b shapes, and the batched program ``PortEngine`` builds for
+corpus kernels on rvv-1024.
+
+The topology is described inside a fixture, never while the module is
+imported: only the test worker that runs this file may load the TPU
+compiler library.  All such compiles live in this one file.
+"""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from benchmarks import xnnpack_suite
+from repro.core.registry import REGISTRY
+from repro.kernels import flash_attention, ops, ssd
+
+CORPUS = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                      "examples", "neon_corpus"))
+sys.path.insert(0, CORPUS)
+
+import harness  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_chip(monkeypatch):
+    """Registered Pallas lowerings pick interpret mode from the default
+    backend, which is the CPU here; compile the real kernels instead."""
+    monkeypatch.setattr(ops, "_interp", lambda: False)
+
+
+def _compile(fn, *shapes):
+    txt = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in txt
+    return txt
+
+
+def _sds(x, sharding):
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("name", xnnpack_suite.FIGURE2_OPS)
+def test_xnnpack_kernel_compiles(name, one_chip, on_chip):
+    _, op, args, kw = next(w for w in xnnpack_suite.workloads()
+                           if w[0] == name)
+    fn, arrs = xnnpack_suite.array_fn(REGISTRY.lowering(op, "pallas").fn,
+                                      args, kw)
+    _compile(fn, *[_sds(a, one_chip) for a in arrs])
+
+
+def test_flash_attention_gemma2_prefill(one_chip):
+    """gemma2-2b prefill: 8 query heads over 4 kv heads, head_dim 256,
+    bf16, local window and logit softcap."""
+    q = jax.ShapeDtypeStruct((4, 8, 512, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((4, 4, 512, 256), jnp.bfloat16,
+                              sharding=one_chip)
+    _compile(lambda q, k, v: flash_attention.flash_attention(
+        q, k, v, causal=True, window=4096, softcap=50.0), q, kv, kv)
+
+
+def test_decode_attention_gemma2(one_chip):
+    q = jax.ShapeDtypeStruct((4, 8, 1, 256), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((4, 4, 528, 256), jnp.bfloat16,
+                              sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
+    _compile(lambda q, k, v, n: flash_attention.decode_attention(
+        q, k, v, n, softcap=50.0), q, kv, kv, lens)
+
+
+def test_ssd_mamba2(one_chip):
+    """mamba2-1.3b: 64 heads of 64, state 128, one group, chunk 128."""
+    def s(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    _compile(lambda x, dt, A, B, C, D: ssd.ssd(x, dt, A, B, C, D,
+                                               chunk=128),
+             s((1, 2048, 64, 64), jnp.bfloat16), s((1, 2048, 64)), s((64,)),
+             s((1, 2048, 1, 128), jnp.bfloat16),
+             s((1, 2048, 1, 128), jnp.bfloat16), s((64,)))
+
+
+@pytest.mark.parametrize("kernel", ["xnn_f32_vadd_ukernel",
+                                    "qs8_gemm_mx8_ukernel"])
+def test_port_engine_program_compiles(kernel, one_chip):
+    """The jit(vmap(...)) program PortEngine serves a batch with, at the
+    padded shapes it builds for an n=4096 request on rvv-1024."""
+    from repro import port
+    from repro.core import targets
+    from repro.serve import PortEngine, Request
+
+    case = next(c for c in harness.cases(n=4096, tail_n=4093)
+                if c.kernel == kernel)
+    k = port.compile_file(os.path.join(CORPUS, case.file), name=kernel)
+    args = case.make_args(np.random.default_rng(0))
+    eng = PortEngine(policy="pallas", revec=True)
+    _, tgt, lens = eng._plan(Request(k, args, target="rvv-1024"))
+    shapes = [jax.ShapeDtypeStruct((eng.max_batch,), jnp.int32,
+                                   sharding=one_chip) if n is None else
+              jax.ShapeDtypeStruct((eng.max_batch, n),
+                                   np.asarray(a).dtype, sharding=one_chip)
+              for a, n in zip(args, lens)]
+    assert tgt == targets.get_target("rvv-1024")
+    eng._program(k, tgt).lower(*shapes).compile()

@@ -161,3 +161,29 @@ def test_elastic_reshard_on_load():
                               shardings={"params": shardings})
         leaf = jax.tree.leaves(loaded["params"])[0]
         assert hasattr(leaf, "sharding")
+
+
+def test_compile_cache_follows_env(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` is left to JAX: the helper reports it
+    and sets no path of its own."""
+    from repro.launch import enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_repo(monkeypatch):
+    """Without the variable the cache sits at a fixed ``<repo>/.jax_cache``
+    (the path is part of the cache key)."""
+    from repro.launch import enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = enable_compile_cache()
+        assert got == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+        assert enable_compile_cache() == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
