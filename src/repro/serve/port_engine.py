@@ -35,6 +35,15 @@ per-request launch overhead dominates.  This engine batches them:
   :meth:`PortEngine.warmup` pre-populates it from a corpus with eager
   (``jit=False``) compiles, the deploy-time shape probe.
 
+* **spans** — each stage of :meth:`PortEngine.submit` is a
+  ``jax.profiler.TraceAnnotation`` (``port.submit`` > ``port.plan``,
+  ``port.chunk`` > ``port.pad``, ``port.h2d``, ``port.launch``,
+  ``port.fetch``, ``port.slice``; ``port.fallback``), recorded on the
+  profiler's host plane, on the device planes' clock, whenever a
+  profiler runs.  Each batched program is named
+  ``port_<kernel>_<target>``, so the trace shows it as
+  ``jit_port_<kernel>_<target>``.
+
 Mixed fleets route per request: ``Request(target="rvv-1024")`` overrides
 the engine default, so rvv-128 and rvv-1024 traffic batch side by side
 in one :meth:`submit` call (grouped separately, like
@@ -43,7 +52,9 @@ in one :meth:`submit` call (grouped separately, like
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
+import re
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -51,6 +62,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from repro.core import targets as _targets
 from repro.port import PortedKernel, revec
@@ -156,6 +168,12 @@ class _ShapeModel:
         return _ShapeModel(counter, tuple(sorted(strides.items())))
 
 
+def _program_name(kernel: str, target: str) -> str:
+    """``port_<kernel>_<target>`` cut down to ``[A-Za-z0-9_]``: the name
+    of the batched program in HLO and in the profiler's trace."""
+    return re.sub(r"[^A-Za-z0-9_]", "_", f"port_{kernel}_{target}")
+
+
 class PortEngine:
     """Batched, bucketed, cache-managed serving of ported kernels.
 
@@ -198,8 +216,10 @@ class PortEngine:
         self._models: Dict[int, _ShapeModel] = {}
         self._programs: Dict[Tuple[int, Any], Any] = {}
         self._shapes_seen: set = set()
+        self._slates = itertools.count(1)   # the spans' ``slate`` arg
         self._stats = {"requests": 0, "batches": 0, "inert_rows": 0,
                        "padded_elems": 0, "payload_elems": 0,
+                       "h2d_bytes": 0, "d2h_bytes": 0,
                        "batch_faults": 0, "row_fallbacks": 0,
                        "errors_returned": 0, "deadline_misses": 0,
                        "program_fallbacks": 0}
@@ -287,7 +307,10 @@ class PortEngine:
                         target=tgt, policy=self.policy,
                         revec=(rung == "compiled+revec"), jit=False,
                         tuned=self.tuned)
-                    prog = jax.jit(jax.vmap(eager))
+                    batched = jax.vmap(eager)
+                    batched.__name__ = _program_name(kernel.fn.name,
+                                                     tgt.name)
+                    prog = jax.jit(batched)
                 except Exception as exc:    # noqa: BLE001 — serve seam
                     err = _resilience.wrap_error(
                         exc, stage="compile", kernel=kernel.fn.name,
@@ -322,17 +345,23 @@ class PortEngine:
         in the results list (``on_error="return"``); the rest of the
         slate is unaffected."""
         t0 = time.monotonic()
-        groups: Dict[Any, List[int]] = {}
-        plans = []
-        for idx, req in enumerate(requests):
-            key, tgt, lens = self._plan(req)
-            plans.append((key, tgt, lens))
-            groups.setdefault(key, []).append(idx)
-        results: List[Any] = [None] * len(requests)
-        for key, members in groups.items():
-            for lo in range(0, len(members), self.max_batch):
-                chunk = members[lo:lo + self.max_batch]
-                self._run_chunk(requests, plans, chunk, results, t0)
+        slate = next(self._slates)
+        with _span("port.submit", slate=slate,
+                   requests=len(requests)) as span:
+            groups: Dict[Any, List[int]] = {}
+            plans = []
+            with _span("port.plan"):
+                for idx, req in enumerate(requests):
+                    key, tgt, lens = self._plan(req)
+                    plans.append((key, tgt, lens))
+                    groups.setdefault(key, []).append(idx)
+            span.set_metadata(groups=len(groups))
+            results: List[Any] = [None] * len(requests)
+            for key, members in groups.items():
+                for lo in range(0, len(members), self.max_batch):
+                    chunk = members[lo:lo + self.max_batch]
+                    self._run_chunk(requests, plans, chunk, results, t0,
+                                    slate)
         self._bump("requests", len(requests))
         return results
 
@@ -343,7 +372,7 @@ class PortEngine:
         return (req.deadline_s is not None and
                 time.monotonic() - t0 >= req.deadline_s)
 
-    def _run_chunk(self, requests, plans, chunk, results, t0):
+    def _run_chunk(self, requests, plans, chunk, results, t0, slate):
         # Expired requests resolve before any compile/launch work; they
         # never hold up their batch-mates.
         live = []
@@ -360,43 +389,56 @@ class PortEngine:
         chunk = live
         if not chunk:
             return
-        req0 = requests[chunk[0]]
-        kernel = req0.kernel
-        _, tgt, lens = plans[chunk[0]]
+        kernel = requests[chunk[0]].kernel
+        key, tgt, lens = plans[chunk[0]]
+        with _span("port.chunk", slate=slate, kernel=kernel.fn.name,
+                   target=tgt.name, bucket=key[2], rows=len(chunk)):
+            self._run_live_chunk(requests, chunk, kernel, tgt, lens,
+                                 results, t0)
+
+    def _run_live_chunk(self, requests, chunk, kernel, tgt, lens,
+                        results, t0):
+        """Pad, transfer, launch, fetch and slice back one chunk whose
+        rows are all live."""
         model = self._model(kernel)
         params = kernel.fn.params
         B = self.max_batch
 
-        cols = []
-        for i, p in enumerate(params):
-            if isinstance(p.type, PtrType):
-                L = lens[i]
-                dt = np.asarray(requests[chunk[0]].args[i]).dtype
-                col = np.zeros((B, L), dtype=dt)
-                for r, idx in enumerate(chunk):
-                    a = np.asarray(requests[idx].args[i])
-                    col[r, :len(a)] = a
-                cols.append(jnp.asarray(col))
-            else:
-                vals = [requests[idx].args[i] for idx in chunk]
-                # inert padding rows: n = 0 makes every trip count zero,
-                # so the zero buffers are never touched
-                pad_val = 0 if i == model.counter else (
-                    vals[0] if vals else 0)
-                vals = vals + [pad_val] * (B - len(chunk))
-                cols.append(jnp.asarray(np.asarray(vals)))
+        with _span("port.pad"):
+            host = []
+            for i, p in enumerate(params):
+                if isinstance(p.type, PtrType):
+                    dt = np.asarray(requests[chunk[0]].args[i]).dtype
+                    col = np.zeros((B, lens[i]), dtype=dt)
+                    for r, idx in enumerate(chunk):
+                        a = np.asarray(requests[idx].args[i])
+                        col[r, :len(a)] = a
+                    host.append(col)
+                else:
+                    vals = [requests[idx].args[i] for idx in chunk]
+                    # inert padding rows: n = 0 makes every trip count
+                    # zero, so the zero buffers are never touched
+                    pad_val = 0 if i == model.counter else vals[0]
+                    vals = vals + [pad_val] * (B - len(chunk))
+                    host.append(np.asarray(vals))
+        with _span("port.h2d"):
+            cols = [jnp.asarray(c) for c in host]
 
-        shape_sig = (id(kernel), tgt,
-                     tuple(None if l is None else l for l in lens))
+        shape_sig = (id(kernel), tgt, tuple(lens))
         with self._lock:
+            new_program = shape_sig not in self._shapes_seen
             self._shapes_seen.add(shape_sig)
             self._stats["batches"] += 1
             self._stats["inert_rows"] += B - len(chunk)
+            self._stats["h2d_bytes"] += sum(c.nbytes for c in cols)
 
         try:
-            _fi.fault_point("engine.batch", kernel=kernel.fn.name,
-                            target=tgt.name)
-            outs = self._program(kernel, tgt)(*cols)
+            # dispatch only: the program runs on the device while the
+            # host waits in port.fetch
+            with _span("port.launch", new_program=int(new_program)):
+                _fi.fault_point("engine.batch", kernel=kernel.fn.name,
+                                target=tgt.name)
+                outs = self._program(kernel, tgt)(*cols)
         except Exception as exc:    # noqa: BLE001 — degrade, never corrupt
             self._bump("batch_faults")
             err = _resilience.wrap_error(
@@ -409,51 +451,59 @@ class PortEngine:
             outs = (outs,)
         # one device->host transfer per output column; per-row numpy
         # slices are free views (vs 32 traced jax slice dispatches)
-        outs = tuple(np.asarray(o) for o in outs)
-        out_params = [i for i, p in enumerate(params)
-                      if isinstance(p.type, PtrType) and p.hint in writes]
-        for r, idx in enumerate(chunk):
-            per_req = []
-            for oi, pi in zip(range(len(writes)), out_params):
-                orig_len = len(requests[idx].args[pi])
-                per_req.append(outs[oi][r, :orig_len])
-                self._bump("payload_elems", orig_len)
-                self._bump("padded_elems", outs[oi].shape[1])
-            results[idx] = (per_req[0] if len(per_req) == 1
-                            else tuple(per_req))
+        with _span("port.fetch"):
+            outs = tuple(np.asarray(o) for o in outs)
+        with _span("port.slice"):
+            out_params = [i for i, p in enumerate(params)
+                          if isinstance(p.type, PtrType) and p.hint in writes]
+            payload = 0
+            for r, idx in enumerate(chunk):
+                per_req = []
+                for out, pi in zip(outs, out_params):
+                    orig_len = len(requests[idx].args[pi])
+                    per_req.append(out[r, :orig_len])
+                    payload += orig_len
+                results[idx] = (per_req[0] if len(per_req) == 1
+                                else tuple(per_req))
+            with self._lock:
+                self._stats["payload_elems"] += payload
+                self._stats["padded_elems"] += len(chunk) * sum(
+                    o.shape[1] for o in outs)
+                self._stats["d2h_bytes"] += sum(o.nbytes for o in outs)
 
     def _fallback_rows(self, requests, chunk, tgt, results, t0, batch_err):
         """Per-row recovery when the batched executable is unavailable:
         each live request descends the full degradation ladder on its
         own (conformance-identical output, just slower).  A row whose
         ladder also exhausts resolves to its typed error."""
-        for idx in chunk:
-            req = requests[idx]
-            if self._deadline_missed(req, t0):
-                self._bump("deadline_misses")
-                err = DeadlineExceeded(
-                    f"deadline of {req.deadline_s}s passed during "
-                    f"batch-fault recovery", kernel=req.kernel.fn.name)
-                err.__cause__ = batch_err
-                results[idx] = self._resolve_error(err)
-                continue
-            remaining = None
-            if req.deadline_s is not None:
-                remaining = max(0.0, req.deadline_s -
-                                (time.monotonic() - t0))
-            try:
-                out, _rec = _resilience.run_resilient(
-                    req.kernel, *req.args, target=tgt, policy=self.policy,
-                    revec=self.revec, jit=False, deadline_s=remaining,
-                    compile_retries=self.compile_retries)
-            except PortError as err:
-                results[idx] = self._resolve_error(err)
-                continue
-            self._bump("row_fallbacks")
-            if isinstance(out, tuple):
-                results[idx] = tuple(np.asarray(o) for o in out)
-            else:
-                results[idx] = np.asarray(out)
+        with _span("port.fallback", rows=len(chunk)):
+            for idx in chunk:
+                req = requests[idx]
+                if self._deadline_missed(req, t0):
+                    self._bump("deadline_misses")
+                    err = DeadlineExceeded(
+                        f"deadline of {req.deadline_s}s passed during "
+                        f"batch-fault recovery", kernel=req.kernel.fn.name)
+                    err.__cause__ = batch_err
+                    results[idx] = self._resolve_error(err)
+                    continue
+                remaining = None
+                if req.deadline_s is not None:
+                    remaining = max(0.0, req.deadline_s -
+                                    (time.monotonic() - t0))
+                try:
+                    out, _rec = _resilience.run_resilient(
+                        req.kernel, *req.args, target=tgt, policy=self.policy,
+                        revec=self.revec, jit=False, deadline_s=remaining,
+                        compile_retries=self.compile_retries)
+                except PortError as err:
+                    results[idx] = self._resolve_error(err)
+                    continue
+                self._bump("row_fallbacks")
+                if isinstance(out, tuple):
+                    results[idx] = tuple(np.asarray(o) for o in out)
+                else:
+                    results[idx] = np.asarray(out)
 
     def _resolve_error(self, err: PortError):
         self._bump("errors_returned")

@@ -248,3 +248,169 @@ def test_compile_cache_info_counts(kernels):
     info = port.compiled_cache_info()
     assert info["misses"] == 1 and info["hits"] == 1
     assert info["size"] == 1
+
+
+# ---------------------------------------------------------------------------
+# spans, program names and transfer counters
+# ---------------------------------------------------------------------------
+
+CHUNK_STAGES = ["port.pad", "port.h2d", "port.launch", "port.fetch",
+                "port.slice"]
+
+
+def _mixed_slate(kernels):
+    """Four groups in five chunks at max_batch=4: five vadd rows in the
+    64 bucket (a chunk of 4 and one of 1), one in the 128 bucket, one
+    vdot and one qs8 dot; 8 live rows."""
+    ns = [("xnn_f32_vadd_ukernel", n) for n in (1, 3, 5, 7, 9)]
+    ns += [("xnn_f32_vadd_ukernel", 70), ("xnn_f32_vdot_ukernel", 7),
+           ("qs8_vmlal_dot_ukernel", 3)]
+    return _requests(kernels, np.random.default_rng(5), ns)
+
+
+def _port_spans(log_dir):
+    """Every ``port.*`` event of the trace under ``log_dir``, in start
+    order: (name, start_ns, end_ns, args)."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    prof = ProfileData.from_file(path)
+    evs = [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+           for plane in prof.planes for line in plane.lines
+           for ev in line.events if ev.name.startswith("port.")]
+    return sorted(evs, key=lambda ev: (ev[1], -ev[2]))
+
+
+def _inside(child, parent):
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def test_submit_spans_nest_with_args(kernels, tmp_path):
+    """Under the profiler, each slate records port.submit > port.plan and
+    one port.chunk per chunk > pad, h2d, launch, fetch, slice in order,
+    with the slate's number, the chunk's live rows and new_program set
+    on the first sight of a shape only."""
+    import jax
+    reqs = _mixed_slate(kernels)
+    eng = PortEngine(target="rvv-128", max_batch=4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.submit(reqs)
+        eng.submit(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    evs = _port_spans(str(tmp_path))
+    submits = [e for e in evs if e[0] == "port.submit"]
+    assert [s[3] for s in submits] == [
+        {"slate": 1, "requests": 8, "groups": 4},
+        {"slate": 2, "requests": 8, "groups": 4}]
+    launches_new = []
+    for sub in submits:
+        inner = [e for e in evs if e is not sub and _inside(e, sub)]
+        plans = [e for e in inner if e[0] == "port.plan"]
+        chunks = [e for e in inner if e[0] == "port.chunk"]
+        assert len(plans) == 1 and len(chunks) == 5
+        assert plans[0][2] <= chunks[0][1]
+        assert sum(c[3]["rows"] for c in chunks) == 8
+        assert sorted(c[3]["rows"] for c in chunks) == [1, 1, 1, 1, 4]
+        for c in chunks:
+            assert c[3]["slate"] == sub[3]["slate"]
+            assert c[3]["target"] == "rvv-128"
+            assert c[3]["bucket"] in (64, 128)
+            assert c[3]["kernel"] in kernels
+            stages = [e for e in inner if _inside(e, c) and e is not c]
+            assert [e[0] for e in stages] == CHUNK_STAGES
+            assert all(a[2] <= b[1] for a, b in zip(stages, stages[1:]))
+            launches_new.append(stages[2][3]["new_program"])
+    # slate 1 sees four shapes first; its second vadd@64 chunk and all of
+    # slate 2 reuse them
+    assert launches_new[:5].count(1) == 4 and launches_new[5:] == [0] * 5
+    assert not any(e[0] == "port.fallback" for e in evs)
+
+
+def test_fallback_span_counts_rows(kernels, tmp_path):
+    """A planted batch fault serves its chunk row by row inside
+    port.fallback, nested in the chunk, after the failed launch."""
+    import jax
+
+    from repro.port import faultinject as fi
+    from repro.port import resilience as rz
+    reqs = _requests(kernels, np.random.default_rng(6),
+                     [("xnn_f32_vdot_ukernel", n) for n in (3, 9, 17)])
+    eng = PortEngine(target="rvv-128", max_batch=4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with fi.injected("engine.batch", error=rz.ExecError, times=None):
+            res = eng.submit(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    assert not any(isinstance(r, Exception) for r in res)
+    evs = _port_spans(str(tmp_path))
+    chunk, = [e for e in evs if e[0] == "port.chunk"]
+    fb, = [e for e in evs if e[0] == "port.fallback"]
+    assert fb[3] == {"rows": 3} and _inside(fb, chunk)
+    names = [e[0] for e in evs if _inside(e, chunk) and e is not chunk
+             and not e[0].startswith("port.fallback")]
+    assert names == ["port.pad", "port.h2d", "port.launch"]
+
+
+@pytest.mark.parametrize("kname,tname,name", [
+    ("xnn_f32_vadd_ukernel", "rvv-128", "port_xnn_f32_vadd_ukernel_rvv_128"),
+    ("qs8_vmlal_dot_ukernel", "rvv-1024",
+     "port_qs8_vmlal_dot_ukernel_rvv_1024")])
+def test_batch_program_named_per_kernel_and_target(kernels, kname, tname,
+                                                   name):
+    """The batched program carries ``port_<kernel>_<target>``, so the
+    trace names it ``jit_port_<kernel>_<target>``."""
+    import jax
+    k = kernels[kname]
+    req = _requests(kernels, np.random.default_rng(7), [(kname, 9)],
+                    target=tname)[0]
+    eng = PortEngine(max_batch=4)
+    _, tgt, lens = eng._plan(req)
+    shapes = [jax.ShapeDtypeStruct((4,), np.int32) if n is None else
+              jax.ShapeDtypeStruct((4, n), np.asarray(a).dtype)
+              for a, n in zip(req.args, lens)]
+    text = eng._program(k, tgt).lower(*shapes).as_text()
+    assert f"@jit_{name} " in text
+
+
+def test_transfer_counters_match_hand_count(kernels):
+    """h2d_bytes counts every column sent (inert rows and the scalar
+    vector included), d2h_bytes every output column fetched."""
+    import jax
+    rng = np.random.default_rng(8)
+    reqs = _requests(kernels, rng, [("xnn_f32_vadd_ukernel", 5),
+                                    ("xnn_f32_vadd_ukernel", 70),
+                                    ("xnn_f32_vdot_ukernel", 7)])
+    eng = PortEngine(target="rvv-128", max_batch=4)
+    eng.submit(reqs)
+    st = eng.stats()
+    n_bytes = jax.dtypes.canonicalize_dtype(np.int64).itemsize
+    f32 = 4
+    # vadd@64: a, b, y (4, 64) f32 + n (4,); vadd@128: (4, 128);
+    # vdot@64: a, b (4, 64) f32, sum (4, 1) f32 + n (4,)
+    h2d = (3 * 4 * 64 * f32 + 4 * n_bytes) + (3 * 4 * 128 * f32
+                                             + 4 * n_bytes) \
+        + (2 * 4 * 64 * f32 + 4 * 1 * f32 + 4 * n_bytes)
+    d2h = 4 * 64 * f32 + 4 * 128 * f32 + 4 * 1 * f32
+    assert (st["h2d_bytes"], st["d2h_bytes"]) == (h2d, d2h)
+
+
+def test_padding_counters_on_a_fixed_slate(kernels):
+    """payload_elems and padded_elems, bumped once per chunk, give the
+    totals of one bump per row and output: 77 requested output elements
+    (5 + 70 + 1 + 1) padded to 194 (64 + 128 + 1 + 1)."""
+    rng = np.random.default_rng(9)
+    reqs = _requests(kernels, rng, [("xnn_f32_vadd_ukernel", 5),
+                                    ("xnn_f32_vadd_ukernel", 70),
+                                    ("xnn_f32_vdot_ukernel", 7),
+                                    ("qs8_vmlal_dot_ukernel", 3)])
+    eng = PortEngine(target="rvv-128", max_batch=4)
+    eng.submit(reqs)
+    eng.submit(reqs[:1])
+    st = eng.stats()
+    assert (st["payload_elems"], st["padded_elems"]) == (77 + 5, 194 + 64)
+    assert st["pad_overhead"] == (194 + 64) / (77 + 5) - 1.0
