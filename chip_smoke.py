@@ -123,6 +123,7 @@ def phase_port(seed: int) -> None:
     log("port", f"{len(cases)} kernels x rvv-128,rvv-1024 through PortEngine"
         f"(policy=pallas, revec) == harness references; degradation "
         f"counters {counters}; batch_programs={s['batch_programs']}; "
+        f"chip_width_programs={s['chip_width_programs']}; "
         f"slate cold {times[0]:.1f}s (compiles) warm {times[1]:.3f}s")
 
 

@@ -110,21 +110,24 @@ class _CompiledKernelCache:
                 and bool(hit.revec) == key[3]
                 and bool(getattr(hit, "jit", key[4])) == key[4]
                 and getattr(hit, "factor_cap", None) == key[5]
-                and getattr(hit, "tail", "auto") == key[6])
+                and getattr(hit, "tail", "auto") == key[6]
+                and getattr(hit, "tile", None) == key[7])
 
     def get(self, kernel: "PortedKernel", *, target=None,
             policy: Optional[str] = "pallas", revec: bool = False,
             jit: bool = True, factor_cap: Optional[int] = None,
-            tail: str = "auto") -> "CompiledKernel":
+            tail: str = "auto", tile=None) -> "CompiledKernel":
         from repro.core import targets as _targets
         tgt = _targets.resolve_target(target)
+        tile = None if tile is None else _targets.get_target(tile)
         # PortedKernel hashes by identity; keeping it in the key also
         # keeps it alive for as long as its compiled variants are cached.
-        # The retile knobs (factor_cap, tail) are part of the key: two
-        # tuned variants of one (kernel, target) are distinct
-        # executables and must not alias.
+        # The retile knobs (factor_cap, tail) and the executing tile are
+        # part of the key: two variants of one (kernel, target) that
+        # re-tile differently are distinct executables and must not
+        # alias.
         key = (kernel, tgt, policy, bool(revec), bool(jit),
-               factor_cap, tail)
+               factor_cap, tail, tile)
         while True:
             with self._lock:
                 hit = self._cache.get(key)
@@ -156,7 +159,7 @@ class _CompiledKernelCache:
                 compiled = CompiledKernel(kernel, target=tgt,
                                           policy=policy, revec=revec,
                                           jit=jit, factor_cap=factor_cap,
-                                          tail=tail)
+                                          tail=tail, tile=tile)
             except BaseException:
                 with self._lock:
                     self._inflight.pop(key, None)
@@ -258,7 +261,7 @@ class PortedKernel:
     def compile(self, *, target=None, policy: Optional[str] = "pallas",
                 revec: bool = False, jit: bool = True,
                 tuned: bool = False, factor_cap: Optional[int] = None,
-                tail: str = "auto") -> "CompiledKernel":
+                tail: str = "auto", tile=None) -> "CompiledKernel":
         """Compile to a single jitted JAX function (one XLA executable
         instead of one Python dispatch per strip iteration).
 
@@ -276,6 +279,14 @@ class PortedKernel:
         policy) are applied; without one the static default compiles
         unchanged.  Explicit ``factor_cap``/``tail`` arguments override
         the cached decision.
+
+        ``tile`` names the fixed-tile machine that will execute the
+        program (see :func:`repro.port.revec.retile`): with
+        ``revec=True`` each strip with a provable masked tail widens to
+        that machine's register tile, while ``target`` (LMUL included,
+        when tuned) keeps selecting every intrinsic's lowering and the
+        factor cap and tail policy govern only the strips left at
+        ``target``'s width.
 
         Results come from the process-wide bounded LRU (see
         :func:`compiled_cache_info`), keyed on this kernel plus the
@@ -296,7 +307,8 @@ class PortedKernel:
                     tail = d.tail
         return _COMPILED_CACHE.get(self, target=tgt, policy=policy,
                                    revec=revec, jit=jit,
-                                   factor_cap=factor_cap, tail=tail)
+                                   factor_cap=factor_cap, tail=tail,
+                                   tile=tile)
 
     def run_resilient(self, *args, target=None,
                       policy: Optional[str] = "pallas", revec: bool = True,
@@ -340,7 +352,7 @@ class CompiledKernel:
     def __init__(self, kernel: PortedKernel, *, target=None,
                  policy: Optional[str] = "pallas", revec: bool = False,
                  jit: bool = True, factor_cap: Optional[int] = None,
-                 tail: str = "auto"):
+                 tail: str = "auto", tile=None):
         from repro.core import targets as _targets
         self.source_kernel = kernel
         self.target = _targets.resolve_target(target)
@@ -349,11 +361,13 @@ class CompiledKernel:
         self.jit = jit
         self.factor_cap = factor_cap
         self.tail = tail
+        self.tile = None if tile is None else _targets.get_target(tile)
         self.retiling: Optional[RetileResult] = None
         fn = kernel.fn
         if revec:
             self.retiling = retile(fn, self.target,
-                                   factor_cap=factor_cap, tail=tail)
+                                   factor_cap=factor_cap, tail=tail,
+                                   tile=self.tile)
             fn = self.retiling.fn
         self.fn = fn
         self._call = compile_fn(fn, policy=policy, target=self.target,

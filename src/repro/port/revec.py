@@ -32,6 +32,14 @@ iteration:
    then runs zero iterations.  Where the masked form is not provably
    safe, a narrow epilogue loop at the original granularity is kept.
 
+A program that a fixed-tile machine executes (``retile(..., tile=)``,
+the batched programs :class:`repro.serve.PortEngine` runs on a TPU)
+widens each strip with a provable masked tail to that machine's
+register tile instead — :meth:`~repro.core.targets.Target.vreg_elems`
+of the narrowest register, 8 x 128 f32 or 32 x 128 int8 elements on
+v5e — while ``target`` still picks every intrinsic's lowering.  Strips
+without a masked-tail plan keep the target's width.
+
 The matcher *assumes* the XNNPACK contract that a scalar tail loop
 computes the per-element residual of the strip body (the corpus
 differential tests check it empirically); everything else is proved
@@ -359,6 +367,10 @@ class RetileResult:
     retiled: int                   # strip loops actually widened
     masked: int                    # widened strips with a predicated tail
     notes: List[str]
+    # strips widened to ``tile``'s register tile (0 without a tile) and
+    # the elements per trip of the widest strip after re-tiling
+    tiled: int = 0
+    strip_elems: int = 0
     # structured narrow-fallback records: {site, reason, detail, line,
     # file} — every strip that stayed narrow says *which* SSA site and
     # source location vetoed it (machine-checkable; notes stay the
@@ -384,7 +396,7 @@ TAIL_POLICIES = ("auto", "masked", "epilogue")
 
 def retile(fn: TFunction, target, strict: bool = False, *,
            factor_cap: Optional[int] = None,
-           tail: str = "auto") -> RetileResult:
+           tail: str = "auto", tile=None) -> RetileResult:
     """Re-tile ``fn``'s strip loops at ``target``'s effective register
     width.  Always returns a function (the original body re-emitted
     unchanged when nothing is re-tilable) plus the decisions taken.
@@ -408,6 +420,13 @@ def retile(fn: TFunction, target, strict: bool = False, *,
       ``"epilogue"`` skips the mask and mops up with a narrow epilogue
       loop where legal.  All three are conformant; they differ only in
       how many instructions the remainder retires.
+
+    ``tile`` names the fixed-tile machine that executes the result (a
+    TPU): each strip with a provable masked tail widens to that
+    machine's register tile for its narrowest register, whatever
+    ``factor_cap`` and ``tail`` say; the rest re-tile at ``target``'s
+    width under those knobs.  ``target`` still selects every
+    intrinsic's lowering.  Everything that models RVV leaves it unset.
     """
     from . import faultinject as _fi
     from .resilience import RevecVeto
@@ -419,7 +438,13 @@ def retile(fn: TFunction, target, strict: bool = False, *,
     if factor_cap is not None and factor_cap < 1:
         raise ValueError(f"factor_cap must be >= 1, got {factor_cap}")
     tgt = _targets.get_target(target)
-    res = _Retiler(fn, tgt, factor_cap=factor_cap, tail=tail).run()
+    if tile is not None:
+        tile = _targets.get_target(tile)
+        if tile.vla:
+            raise ValueError(f"tile must be a fixed-tile machine, "
+                             f"not {tile.name!r}")
+    res = _Retiler(fn, tgt, factor_cap=factor_cap, tail=tail,
+                   tile=tile).run()
     if strict and res.strips > 0 and res.retiled == 0:
         raise RevecVeto(
             f"no strip loop could be re-tiled at {tgt.name} "
@@ -430,11 +455,13 @@ def retile(fn: TFunction, target, strict: bool = False, *,
 
 class _Retiler:
     def __init__(self, fn: TFunction, tgt: _targets.Target, *,
-                 factor_cap: Optional[int] = None, tail: str = "auto"):
+                 factor_cap: Optional[int] = None, tail: str = "auto",
+                 tile: Optional[_targets.Target] = None):
         self.fn = fn
         self.tgt = tgt
         self.factor_cap = factor_cap
         self.tail = tail
+        self.tile = tile
         self.notes: List[str] = []
         self.vetoes: List[dict] = []
         self.vmap: Dict[int, Value] = {}       # id(old Value) -> new
@@ -442,6 +469,8 @@ class _Retiler:
         self.strips = {id(s.loop): s for s in strip_loops(fn)}
         self.retiled = 0
         self.masked = 0
+        self.tiled = 0
+        self.strip_elems = 0
         self.factor_used = 1
         self._ids = itertools.count(_max_id(fn) + 1)
         # per-strip legality scratch (reset in retile_strip)
@@ -491,7 +520,8 @@ class _Retiler:
                             factor=self.factor_used,
                             strips=len(self.strips), retiled=self.retiled,
                             masked=self.masked, notes=self.notes,
-                            vetoes=self.vetoes,
+                            vetoes=self.vetoes, tiled=self.tiled,
+                            strip_elems=self.strip_elems,
                             factor_cap=self.factor_cap, tail=self.tail)
 
     # -- generic region copy ----------------------------------------------
@@ -504,6 +534,7 @@ class _Retiler:
             if strip is not None:
                 if strip.scalable and self.retile_strip(strip, dst):
                     continue
+                self.strip_elems = max(self.strip_elems, strip.step)
                 if not strip.scalable:
                     self.notes.append(
                         f"loop kept at {strip.step}-element strips: "
@@ -550,21 +581,30 @@ class _Retiler:
         # spilling into a double register group exactly like RVV's
         # widening ops write 2xLMUL destinations (the cost models charge
         # the extra register micro-ops, so the estimate stays honest).
-        factor = None
-        for ty in _body_vec_types(loop):
-            f = self.tgt.retile_factor(ty.lanes, ty.dtype)
-            factor = f if factor is None else max(factor, f)
-        if factor and self.factor_cap is not None:
+        tys = _body_vec_types(loop)
+        factor = max((self.tgt.retile_factor(ty.lanes, ty.dtype)
+                      for ty in tys), default=1)
+        if self.factor_cap is not None:
             # tuning knob: the autotuner may bound widening below the
             # register group's natural headroom (cap 1 == stay narrow)
             factor = min(factor, self.factor_cap)
-        if not factor or factor <= 1:
+        # the executing machine's tile, by the same narrowest-register
+        # rule; it applies only under a provable masked tail (below)
+        tile_factor = 1
+        if self.tile is not None:
+            tile_factor = max((self.tile.vreg_elems(ty.dtype) // ty.lanes
+                               for ty in tys), default=1)
+
+        def no_headroom() -> bool:
             self.notes.append(
                 f"strip at {strip.step} elems/iter: no width headroom "
                 f"on {self.tgt.name}"
                 + (f" (factor_cap={self.factor_cap})"
                    if self.factor_cap is not None else ""))
             return False
+
+        if factor <= 1 and tile_factor <= 1:
+            return no_headroom()
         self._group_loads = set()
         self._fold_phis = set()
         if any(isinstance(v.type, VecTupleType)
@@ -581,7 +621,15 @@ class _Retiler:
             return False
 
         plan = (self.plan_masked_tail(strip)
-                if self.tail in ("auto", "masked") else None)
+                if tile_factor > 1 or self.tail in ("auto", "masked")
+                else None)
+        if tile_factor > 1 and plan is not None:
+            return self.widen_strip(strip, tile_factor, plan, dst,
+                                    at_tile=True)
+        if self.tail == "epilogue":
+            plan = None
+        if factor <= 1:
+            return no_headroom()
         if self.tail == "epilogue" and self._fold_phis:
             # a foldable accumulator's group fold only folds correctly
             # under a masked tail; without one the strip must not widen
@@ -605,13 +653,22 @@ class _Retiler:
                 "no-tail-coverage",
                 "accumulator strip without masked tail or scalar tail "
                 "cannot cover the remainder; kept narrow")
+        return self.widen_strip(strip, factor, plan, dst, at_tile=False)
 
+    def widen_strip(self, strip: StripInfo, factor: int, plan,
+                    dst: Block, at_tile: bool) -> bool:
+        """Emit the widened loop and its remainder (masked tail under
+        ``plan``, else a narrow epilogue or the scalar tail)."""
+        tail_exists = _tail_consumes(strip)
         self.factor_used = max(self.factor_used, factor)
+        self.strip_elems = max(self.strip_elems, strip.step * factor)
         self.retiled += 1
+        self.tiled += int(at_tile)
         saved = dict(self.vmap)
         tile_map: Dict[int, Value] = {}
+        where = f"the {self.tile.name} tile" if at_tile else self.tgt.name
         new_loop, result_map = self.widen_loop(strip, factor, dst,
-                                               tile_map)
+                                               tile_map, where)
         if plan is not None:
             # masked predicated tail subsumes remainder (+ scalar tail)
             self.vmap = dict(saved)
@@ -1113,7 +1170,7 @@ class _Retiler:
 
     # -- widened main loop -------------------------------------------------
     def widen_loop(self, strip: StripInfo, factor: int, dst: Block,
-                   tile_map: Dict[int, Value]):
+                   tile_map: Dict[int, Value], where: str):
         loop = strip.loop
 
         # widen loop-invariant vector registers used inside the body
@@ -1147,7 +1204,7 @@ class _Retiler:
         dst.instrs.append(new)
         self.notes.append(
             f"strip re-tiled {strip.step} -> {strip.step * factor} "
-            f"elems/iter on {self.tgt.name} ({factor}x)")
+            f"elems/iter on {where} ({factor}x)")
         return new, result_map
 
     def emit_tile(self, v: Value, factor: int, dst: Block,

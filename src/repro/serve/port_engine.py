@@ -30,6 +30,17 @@ per-request launch overhead dominates.  This engine batches them:
   length-1 ``sum`` output of a dot kernel, packed weights) keep their
   exact length and join the group key instead.
 
+* **chip-width strips** — the batched program runs on a fixed-tile
+  machine, not on the RVV machine a request names, so its first rung
+  re-tiles each strip that has a provable masked tail to the executing
+  chip's register tile (:func:`repro.core.targets.compile_target`;
+  8 x 128 f32 or 32 x 128 int8 elements a trip on v5e) while the
+  request's target still selects every intrinsic's lowering.  One trip
+  then covers 1,024-4,096 elements instead of 4-128, and the masked
+  tail becomes the padding mask.  ``stats()["chip_width_programs"]``
+  counts the programs built so, and ``port.chunk``'s ``strip`` arg
+  gives a program's elements per trip.
+
 * **compile reuse** — all compilation goes through the process-wide
   bounded CompiledKernel LRU (:func:`repro.port.compiled_cache_info`);
   :meth:`PortEngine.warmup` pre-populates it from a corpus with eager
@@ -215,6 +226,9 @@ class PortEngine:
         self._lock = threading.RLock()
         self._models: Dict[int, _ShapeModel] = {}
         self._programs: Dict[Tuple[int, Any], Any] = {}
+        # (kernel, target) -> elements per trip of its program's widest
+        # strip (the ``port.chunk`` span's ``strip``)
+        self._strips: Dict[Tuple[int, Any], int] = {}
         self._shapes_seen: set = set()
         self._slates = itertools.count(1)   # the spans' ``slate`` arg
         self._stats = {"requests": 0, "batches": 0, "inert_rows": 0,
@@ -222,7 +236,7 @@ class PortEngine:
                        "h2d_bytes": 0, "d2h_bytes": 0,
                        "batch_faults": 0, "row_fallbacks": 0,
                        "errors_returned": 0, "deadline_misses": 0,
-                       "program_fallbacks": 0}
+                       "program_fallbacks": 0, "chip_width_programs": 0}
 
     def _bump(self, key: str, n: int = 1) -> None:
         with self._lock:
@@ -275,15 +289,24 @@ class PortEngine:
 
     # -- batch programs ----------------------------------------------------
 
+    def _eager(self, kernel: PortedKernel, tgt, revec: bool):
+        """The eager (``jit=False``) compile of one batched rung, from
+        the process-wide LRU; the revec rung re-tiles at the executing
+        chip's tile."""
+        return kernel.compile(
+            target=tgt, policy=self.policy, revec=revec, jit=False,
+            tuned=self.tuned,
+            tile=_targets.compile_target() if revec else None)
+
     def _program(self, kernel: PortedKernel, tgt):
         """The jitted vmapped executable for (kernel, target).
 
-        Compiles down the batched rungs (revec first, then narrow) with
-        bounded transient retry and the process-wide breaker: a rung
-        whose breaker is open is skipped without an attempt, and a
-        success closes it again.  Raises a typed :class:`PortError`
-        only when every batched rung is out — the caller then degrades
-        to per-row recovery."""
+        Compiles down the batched rungs (revec at the chip's tile
+        first, then narrow) with bounded transient retry and the
+        process-wide breaker: a rung whose breaker is open is skipped
+        without an attempt, and a success closes it again.  Raises a
+        typed :class:`PortError` only when every batched rung is out —
+        the caller then degrades to per-row recovery."""
         pk = (id(kernel), tgt)
         with self._lock:
             prog = self._programs.get(pk)
@@ -303,10 +326,8 @@ class PortEngine:
                     # eager (jit=False) compile from the process-wide
                     # LRU; the jit wraps the *vmapped* callable so one
                     # executable serves the whole batch
-                    eager = kernel.compile(
-                        target=tgt, policy=self.policy,
-                        revec=(rung == "compiled+revec"), jit=False,
-                        tuned=self.tuned)
+                    eager = self._eager(kernel, tgt,
+                                        rung == "compiled+revec")
                     batched = jax.vmap(eager)
                     batched.__name__ = _program_name(kernel.fn.name,
                                                      tgt.name)
@@ -322,10 +343,18 @@ class PortEngine:
                     last_err = err
                     break
                 brk.success(bkey)
+                rt = eager.retiling
+                strip = (rt.strip_elems if rt is not None else
+                         max((s.step for s in revec.strip_loops(kernel.fn)),
+                             default=0))
                 with self._lock:
                     self._programs[pk] = prog
+                    self._strips[pk] = strip
                     if rung != rungs[0]:
                         self._stats["program_fallbacks"] += 1
+                    if rt is not None and rt.strips and \
+                            rt.tiled == rt.strips:
+                        self._stats["chip_width_programs"] += 1
                 return prog
         if last_err is not None:
             raise last_err
@@ -392,14 +421,16 @@ class PortEngine:
         kernel = requests[chunk[0]].kernel
         key, tgt, lens = plans[chunk[0]]
         with _span("port.chunk", slate=slate, kernel=kernel.fn.name,
-                   target=tgt.name, bucket=key[2], rows=len(chunk)):
+                   target=tgt.name, bucket=key[2],
+                   rows=len(chunk)) as span:
             self._run_live_chunk(requests, chunk, kernel, tgt, lens,
-                                 results, t0)
+                                 results, t0, span)
 
     def _run_live_chunk(self, requests, chunk, kernel, tgt, lens,
-                        results, t0):
+                        results, t0, span):
         """Pad, transfer, launch, fetch and slice back one chunk whose
-        rows are all live."""
+        rows are all live; ``span`` (the chunk's) gets the program's
+        ``strip``."""
         model = self._model(kernel)
         params = kernel.fn.params
         B = self.max_batch
@@ -438,7 +469,9 @@ class PortEngine:
             with _span("port.launch", new_program=int(new_program)):
                 _fi.fault_point("engine.batch", kernel=kernel.fn.name,
                                 target=tgt.name)
-                outs = self._program(kernel, tgt)(*cols)
+                prog = self._program(kernel, tgt)
+                span.set_metadata(strip=self._strips[(id(kernel), tgt)])
+                outs = prog(*cols)
         except Exception as exc:    # noqa: BLE001 — degrade, never corrupt
             self._bump("batch_faults")
             err = _resilience.wrap_error(
@@ -536,8 +569,7 @@ class PortEngine:
         for k in kernels:
             self._model(k)          # derive the padding rules up front
             for t in tgts:
-                k.compile(target=t, policy=self.policy,
-                          revec=self.revec, jit=False, tuned=self.tuned)
+                self._eager(k, t, self.revec)
                 n += 1
         return {"kernels": len(kernels), "targets": len(tgts),
                 "compiles": n}
@@ -548,7 +580,9 @@ class PortEngine:
         """Serving counters.  ``batch_programs`` counts distinct
         (kernel, target, canonical shape) signatures — the number of
         XLA executables this engine has demanded, bounded by
-        buckets x targets x kernels."""
+        buckets x targets x kernels.  ``chip_width_programs`` counts
+        the batched (kernel, target) programs whose strips all run at
+        the chip's tile."""
         from repro import port as _port
         with self._lock:
             s = dict(self._stats)

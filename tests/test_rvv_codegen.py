@@ -226,3 +226,40 @@ def test_golden_emitted_c(name):
     assert got == want, \
         f"{name}: emitted C drifted from {os.path.relpath(path, ROOT)} " \
         f"— regenerate via rvv.emit(k, 'rvv-256').render_c() and review"
+
+
+# ---------------------------------------------------------------------------
+# the RVV side keeps the RVV width
+# ---------------------------------------------------------------------------
+
+def _committed(name):
+    import json
+    with open(os.path.join(ROOT, name)) as f:
+        return json.load(f)["kernels"]
+
+
+@pytest.mark.parametrize("kernel", sorted(CASES))
+def test_rvv_counts_pinned_to_committed_baselines(kernel):
+    """What models RVV re-tiles at the RVV width, never at a chip's
+    tile: on rvv-128 and rvv-1024, revec's factor and its retiled and
+    masked strip counts equal ``BENCH_port.json``'s, and the emitted
+    RVV's retired counts on the simulator (at the rvv_sim suite's
+    n = 1024, tail 1027) equal ``BENCH_rvv_sim.json``'s."""
+    port_rows = _committed("BENCH_port.json")[kernel]["targets"]
+    sim_rows = _committed("BENCH_rvv_sim.json")[kernel]["targets"]
+    case = next(c for c in harness.cases(n=1024, tail_n=1027)
+                if c.kernel == kernel)
+    k = _kernel(kernel)
+    args = case.make_args(np.random.default_rng(0))
+    for target in ("rvv-128", "rvv-1024"):
+        res = port.retile(k.fn, target)
+        assert (res.factor, res.retiled, res.masked) == (
+            port_rows[target]["retile_factor"],
+            port_rows[target]["retiled_strips"],
+            port_rows[target]["masked_tails"]), target
+        assert res.tiled == 0
+        _, counts = rvv.execute(rvv.emit(k, target), *args)
+        got = {"executed": counts["executed"], "vector": counts["vector"],
+               "vsetvli": counts["vsetvli"] + counts["implicit_vsetvli"],
+               "vuops": counts["vuops"]}
+        assert got == sim_rows[target], target

@@ -3,6 +3,7 @@ executable bound, and the process-wide CompiledKernel cache semantics
 (including the resolved-target keying regression)."""
 import dataclasses
 import os
+import sys
 
 import numpy as np
 import pytest
@@ -13,6 +14,9 @@ from repro.serve import BucketPolicy, PortEngine, Request
 
 CORPUS = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
                                       "examples", "neon_corpus"))
+sys.path.insert(0, CORPUS)
+
+import harness  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -414,3 +418,78 @@ def test_padding_counters_on_a_fixed_slate(kernels):
     st = eng.stats()
     assert (st["payload_elems"], st["padded_elems"]) == (77 + 5, 194 + 64)
     assert st["pad_overhead"] == (194 + 64) / (77 + 5) - 1.0
+
+
+# ---------------------------------------------------------------------------
+# chip-width strips: the batched program's first rung runs each strip at
+# the executing chip's register tile
+# ---------------------------------------------------------------------------
+
+# the benchmark's eight corpus kernels, with the element type of the
+# narrowest register in each strip (it sets the tile)
+CHIP_KERNELS = [("vadd.c", "xnn_f32_vadd_ukernel", np.float32),
+                ("vmul.c", "xnn_f32_vmul_ukernel", np.float32),
+                ("vmull_requant.c", "qs8_vmul_requant_ukernel", np.int8),
+                ("vclamp.c", "xnn_f32_vclamp_ukernel", np.float32),
+                ("vmlal_dot.c", "qs8_vmlal_dot_ukernel", np.int8),
+                ("vtanh.c", "xnn_f32_vtanh_ukernel", np.float32),
+                ("vsigmoid.c", "xnn_f32_vsigmoid_ukernel", np.float32),
+                ("vdot.c", "xnn_f32_vdot_ukernel", np.float32)]
+LARGEST_BUCKET = 65536
+REDUCTION_BUDGET_U = 8.0        # float32 unit roundoffs x sum of |terms|
+
+
+def _case(kname, n):
+    """The harness case of ``kname`` at ``n`` (buffers hold at least one
+    element, so the unbatched narrow program can trace n = 0)."""
+    m = max(1, n)
+    return next(c for c in harness.cases(n=m, tail_n=m)
+                if c.kernel == kname)
+
+
+@pytest.mark.parametrize("target", ["rvv-128", "rvv-1024"])
+@pytest.mark.parametrize("fname,kname,dtype", CHIP_KERNELS)
+def test_chip_width_program_matches_narrow_and_reference(fname, kname, dtype,
+                                                         target, tmp_path):
+    """The chip-width program answers n in {0, 1, tile - 1, tile,
+    tile + 1, largest bucket}, beside inert rows, as the narrow compiled
+    program does (bitwise; the f32 dot within its reduction budget) and
+    as the NumPy reference does; it is built on the first rung, and its
+    chunks carry the tile as ``strip``."""
+    import jax
+    k = port.compile_file(os.path.join(CORPUS, fname), name=kname)
+    tile = targets.compile_target().vreg_elems(dtype)
+    rng = np.random.default_rng(11)
+    ns = [0, 1, tile - 1, tile, tile + 1, LARGEST_BUCKET]
+    reqs, cases = [], []
+    for n in ns:
+        case = _case(kname, n)
+        args = list(case.make_args(rng))
+        args[0] = n
+        reqs.append(Request(k, tuple(args), target=target))
+        cases.append(case)
+    eng = PortEngine(target=target, max_batch=4)
+    outs = eng.submit(reqs)
+    narrow = k.compile(target=target)
+    for req, case, got in zip(reqs, cases, outs):
+        n, args = req.args[0], req.args
+        want = np.asarray(narrow(*args))
+        ref = case.reference(*args)
+        if kname == "xnn_f32_vdot_ukernel":
+            a, b = (np.asarray(x, np.float64)[:n] for x in args[1:3])
+            bound = REDUCTION_BUDGET_U * 2.0 ** -24 * np.abs(a * b).sum()
+            for other in (want, ref):
+                assert abs(float(got[0]) - float(other[0])) <= bound, n
+        else:
+            np.testing.assert_array_equal(got, want, err_msg=f"n={n}")
+            harness.assert_conforms(got, ref, case, f"{kname} n={n}")
+    st = eng.stats()
+    assert st["chip_width_programs"] == 1
+    assert st["program_fallbacks"] == 0 and st["batch_faults"] == 0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.submit(reqs[1:2])
+    finally:
+        jax.profiler.stop_trace()
+    chunk, = [e for e in _port_spans(str(tmp_path)) if e[0] == "port.chunk"]
+    assert chunk[3]["strip"] == tile

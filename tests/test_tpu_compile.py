@@ -129,3 +129,33 @@ def test_port_engine_program_compiles(kernel, one_chip):
               for a, n in zip(args, lens)]
     assert tgt == targets.get_target("rvv-1024")
     eng._program(k, tgt).lower(*shapes).compile()
+
+
+@pytest.mark.parametrize("kernel,dtype", [("xnn_f32_vmul_ukernel", np.float32),
+                                          ("qs8_vmul_requant_ukernel",
+                                           np.int8)])
+def test_chip_width_program_compiles(kernel, dtype, one_chip):
+    """PortEngine's chip-width program (strips at v5e's 8 x 128 f32 or
+    32 x 128 int8 tile) for a full batch of 32 rows in the largest
+    bucket the benchmark serves, 65,536 elements, on rvv-128."""
+    from repro import port
+    from repro.core import targets
+    from repro.serve import PortEngine, Request
+
+    n = 65536
+    case = next(c for c in harness.cases(n=n, tail_n=n)
+                if c.kernel == kernel)
+    k = port.compile_file(os.path.join(CORPUS, case.file), name=kernel)
+    args = case.make_args(np.random.default_rng(0))
+    eng = PortEngine(policy="pallas", revec=True, max_batch=32)
+    _, tgt, lens = eng._plan(Request(k, args, target="rvv-128"))
+    assert lens[1:] == [n, n, n]
+    shapes = [jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+              if m is None else
+              jax.ShapeDtypeStruct((32, m), np.asarray(a).dtype,
+                                   sharding=one_chip)
+              for a, m in zip(args, lens)]
+    prog = eng._program(k, tgt)
+    assert eng._strips[(id(k), tgt)] == \
+        targets.get_target("tpu-v5e").vreg_elems(dtype)
+    prog.lower(*shapes).compile()
