@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import importlib
 
-from .base import SHAPES, ModelConfig, ShapeConfig
+from .base import SHAPES, ModelConfig, ShapeConfig, YarnScaling
 
 _ARCHS = {
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
@@ -32,5 +32,5 @@ def all_configs():
     return {name: get_config(name) for name in ARCH_NAMES}
 
 
-__all__ = ["ARCH_NAMES", "SHAPES", "ModelConfig", "ShapeConfig",
+__all__ = ["ARCH_NAMES", "SHAPES", "ModelConfig", "ShapeConfig", "YarnScaling",
            "get_config", "all_configs"]

@@ -8,6 +8,21 @@ _MISSING = object()
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 states it:
+    frequencies blend interpolation (``/ factor``) and extrapolation
+    between the correction dims of ``beta_fast`` and ``beta_slow``
+    rotations over ``original_max_position_embeddings``; the attention
+    softmax scale gains ``yarn_mscale(factor, mscale_all_dim) ** 2``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                      # dense | moe | ssm | hybrid | encdec | vlm
@@ -27,6 +42,7 @@ class ModelConfig:
     softcap: Optional[float] = None          # attention logit softcap (gemma2)
     final_softcap: Optional[float] = None    # final logit softcap (gemma2)
     qk_norm: bool = False            # gemma3 per-head q/k rmsnorm
+    rope_scaling: Optional[YarnScaling] = None
 
     # MLA (deepseek-v2 / minicpm3) -------------------------------------------
     q_lora_rank: int = 0             # 0 = dense q projection
@@ -40,7 +56,8 @@ class ModelConfig:
     n_shared_experts: int = 0
     top_k: int = 0
     d_expert: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # train-mode dispatch; serving drops none
+    norm_topk_prob: bool = True      # divide the top-k gates by their sum
     first_dense_layers: int = 0      # deepseek-v2: first layer uses dense FFN
     d_ff_dense: int = 0              # FFN width of those dense layers
 

@@ -75,6 +75,27 @@ def gemm(a, b, bias=None, clamp_min=float("-inf"), clamp_max=float("inf"),
 
 
 # ---------------------------------------------------------------------------
+# grouped gemm (rows sorted by group; one weight matrix per group)
+# ---------------------------------------------------------------------------
+
+def _grouped_gemm_cost(x, w, group_sizes, **_):
+    return 2 * x.shape[0] * x.shape[1] * w.shape[2]
+
+
+@register("grouped_gemm", "vector", cost=_grouped_gemm_cost,
+          doc="jax.lax.ragged_dot (XLA's grouped matmul on TPU)")
+def _grouped_gemm_vector(x, w, group_sizes):
+    return jax.lax.ragged_dot(x, w, group_sizes)
+
+
+def grouped_gemm(x, w, group_sizes, *, policy=None, target=None):
+    """x:(M, K) rows sorted by group, w:(G, K, N), group_sizes:(G,) ->
+    (M, N): rows of group g times w[g]; rows past sum(group_sizes) are 0."""
+    return dispatch("grouped_gemm", x, w, group_sizes, policy=policy,
+                    target=target)
+
+
+# ---------------------------------------------------------------------------
 # convolutions
 # ---------------------------------------------------------------------------
 
@@ -277,9 +298,9 @@ register("attention", "vector", cost=trace.traced_cost(_attn_vector),
 
 def _attn_supports(q, k, v, causal=True, window=None, softcap=None,
                    scale=None):
-    # the fused kernel requires equal q/v head dims (MLA's split dims fall
-    # back to the vector tier — the paper's validity-predicate pattern)
-    return (q.shape[-1] == v.shape[-1] and
+    # a value head narrower than the query head (MLA: 128 under 192) is
+    # zero-padded to it; both round up to the same lane multiple
+    return (v.shape[-1] <= q.shape[-1] and
             _fa.supports(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v))
 
 
@@ -289,11 +310,14 @@ def _attn_supports(q, k, v, causal=True, window=None, softcap=None,
               v.transpose(0, 2, 1, 3), causal=causal),
           doc="online-softmax flash attention, VMEM-resident stats")
 def _attn_pallas(q, k, v, causal=True, window=None, softcap=None, scale=None):
+    dv = v.shape[-1]
+    if dv < q.shape[-1]:
+        v = jnp.pad(v, [(0, 0)] * 3 + [(0, q.shape[-1] - dv)])
     out = _fa.flash_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), causal=causal, window=window,
         softcap=softcap, scale=scale, interpret=_interp())
-    return out.transpose(0, 2, 1, 3)
+    return out.transpose(0, 2, 1, 3)[..., :dv]
 
 
 def attention(q, k, v, *, causal=True, window=None, softcap=None, scale=None,

@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import ops
 from . import layers as L
@@ -139,6 +138,21 @@ def mla_cache_init(cfg, batch, s_max, dtype=None):
             "k_rope": jnp.zeros((batch, s_max, cfg.qk_rope_dim), dt)}
 
 
+def mla_softmax_scale(cfg) -> float:
+    """``(qk_nope + qk_rope) ** -0.5``, times ``yarn_mscale(factor,
+    mscale_all_dim) ** 2`` under YaRN scaling with ``mscale_all_dim`` set
+    (DeepSeek-V2's ``DeepseekV2Attention.softmax_scale``)."""
+    scale = float(cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= L.yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
+
+
+def _rope(cfg, x, positions):
+    return L.rope_apply(x, positions, cfg.rope_theta, cfg.rope_scaling)
+
+
 def _mla_q(params, x, cfg, positions):
     b, s, _ = x.shape
     h = cfg.n_heads
@@ -150,7 +164,7 @@ def _mla_q(params, x, cfg, positions):
         q = L.linear(params["wq"], x)
     q = q.reshape(b, s, h, nd + r)
     q_nope, q_rope = q[..., :nd], q[..., nd:]
-    q_rope = L.rope_apply(q_rope, positions, cfg.rope_theta)
+    q_rope = _rope(cfg, q_rope, positions)
     return q_nope, q_rope
 
 
@@ -159,17 +173,23 @@ def _mla_ckv(params, x, cfg, positions):
     r = cfg.qk_rope_dim
     dkv = L.linear(params["w_dkv"], x)
     c_kv = L.norm_apply(params["kv_norm"], dkv[..., :cfg.kv_lora_rank])
-    k_rope = L.rope_apply(dkv[..., cfg.kv_lora_rank:][:, :, None, :],
-                          positions, cfg.rope_theta)[:, :, 0]
+    k_rope = _rope(cfg, dkv[..., cfg.kv_lora_rank:][:, :, None, :],
+                   positions)[:, :, 0]
     return c_kv, k_rope
 
 
 def mla_apply(params, x, cfg, *, positions, mode, cache=None, lengths=None,
               target=None, **_):
+    with jax.named_scope("mla"):
+        return _mla_apply(params, x, cfg, positions=positions, mode=mode,
+                          cache=cache, lengths=lengths, target=target)
+
+
+def _mla_apply(params, x, cfg, *, positions, mode, cache, lengths, target):
     b, s, _ = x.shape
     h = cfg.n_heads
     r, nd, vd = cfg.qk_rope_dim, cfg.qk_nope_dim, cfg.v_head_dim
-    scale = 1.0 / np.sqrt(nd + r)
+    scale = mla_softmax_scale(cfg)
     q_nope, q_rope = _mla_q(params, x, cfg, positions)
 
     if mode in ("train", "prefill"):

@@ -29,6 +29,18 @@ class Ctx:
     shared: Any = None             # zamba2: shared block params
     target: Any = None             # explicit lowering target (per-request
                                    # multi-backend serving); None = ambient
+    valid: Optional[jnp.ndarray] = None     # (B, S) live tokens; None = all
+    dropless: bool = False         # MoE keeps every assignment (serving)
+
+
+def no_aux(cfg):
+    """A block's auxiliary outputs, summed over layers by the model: the
+    MoE load-balance loss, live assignments per expert, assignments
+    dropped and experts given any (zeros for blocks without experts)."""
+    return {"lb": jnp.zeros((), jnp.float32),
+            "expert_tokens": jnp.zeros((cfg.n_experts,), jnp.int32),
+            "dropped": jnp.zeros((), jnp.int32),
+            "experts_touched": jnp.zeros((), jnp.int32)}
 
 
 def _attn_impl(cfg):
@@ -77,11 +89,15 @@ def _tblock_apply(params, x, cache, ctx: Ctx, *, ffn: str, window=None):
         h = L.norm_apply(params["ln1p"], h, cfg.norm)
     x = x + h
     h = L.norm_apply(params["ln2"], x, cfg.norm)
-    aux = jnp.zeros((), jnp.float32)
+    aux = no_aux(cfg)
     if ffn == "moe":
-        h, aux = M.moe_apply(params["ffn"], h, cfg)
+        h, lb, st = M.moe_apply(params["ffn"], h, cfg,
+                                dropless=ctx.dropless, valid=ctx.valid,
+                                stats=True)
+        aux = {"lb": lb, **st}
     else:
-        h = L.mlp_apply(params["ffn"], h, cfg)
+        with jax.named_scope("dense_mlp"):
+            h = L.mlp_apply(params["ffn"], h, cfg)
     if cfg.sandwich_norm:
         h = L.norm_apply(params["ln2p"], h, cfg.norm)
     return x + h, cache, aux
@@ -100,7 +116,7 @@ def _mamba_apply(params, x, cache, ctx: Ctx):
     h = L.norm_apply(params["ln"], x, ctx.cfg.norm)
     h, cache = S.mamba_apply(params["mamba"], h, ctx.cfg, mode=ctx.mode,
                              cache=cache, target=ctx.target)
-    return x + h, cache, jnp.zeros((), jnp.float32)
+    return x + h, cache, no_aux(ctx.cfg)
 
 
 def shared_block_init(key, cfg):
@@ -158,8 +174,7 @@ def _enc_apply(params, x, cache, ctx: Ctx):
                        mode="train", causal=False, target=ctx.target)
     x = x + h
     h = L.norm_apply(params["ln2"], x, cfg.norm)
-    return x + L.mlp_apply(params["mlp"], h, cfg), cache, \
-        jnp.zeros((), jnp.float32)
+    return x + L.mlp_apply(params["mlp"], h, cfg), cache, no_aux(cfg)
 
 
 def _dec_init(key, cfg):
@@ -207,7 +222,7 @@ def _dec_apply(params, x, cache, ctx: Ctx):
     x = x + L.mlp_apply(params["mlp"], h, cfg)
     if cache is not None:
         cache = {"self": self_cache, "xk": xk, "xv": xv}
-    return x, cache, jnp.zeros((), jnp.float32)
+    return x, cache, no_aux(cfg)
 
 
 # ---------------------------------------------------------------------------

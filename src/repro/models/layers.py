@@ -114,14 +114,57 @@ def act_apply(x, kind):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_apply(x, positions, theta):
-    """x:(B, S, H, D) rotate with half-split RoPE at ``positions``:(B, S)."""
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature factor (DeepSeek's ``yarn_get_mscale``)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
+def rope_frequencies(dim: int, theta: float, scaling=None) -> np.ndarray:
+    """Inverse frequencies of the ``dim // 2`` rotated pairs, float32.
+
+    With ``scaling`` (a :class:`~repro.configs.base.YarnScaling`) the
+    frequencies blend interpolation (``/ factor``) and extrapolation
+    with a linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow`` rotations over the original context, as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding`` computes them."""
+    half = dim // 2
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    if scaling is None:
+        return extra.astype(np.float32)
+    inter = extra / scaling.factor
+    orig = scaling.original_max_position_embeddings
+
+    def corr_dim(rot):
+        return dim * np.log(orig / (rot * 2 * np.pi)) / (2 * np.log(theta))
+
+    low = max(int(np.floor(corr_dim(scaling.beta_fast))), 0)
+    high = min(int(np.ceil(corr_dim(scaling.beta_slow))), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0.0, 1.0)
+    keep = 1.0 - ramp                   # 1 = extrapolate (high frequency)
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def rope_apply(x, positions, theta, scaling=None):
+    """x:(B, S, H, D) rotated with half-split RoPE at ``positions``:(B, S).
+    ``scaling`` selects YaRN frequencies and multiplies cos/sin by
+    ``mscale / mscale_all_dim``'s ratio (1 where the two are equal, as in
+    DeepSeek-V2)."""
     b, s, h, d = x.shape
     half = d // 2
-    freqs = (theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = jnp.asarray(rope_frequencies(d, theta, scaling))
     ang = positions.astype(jnp.float32)[..., None] * freqs          # (B,S,half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale) /
+             yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

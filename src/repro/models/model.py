@@ -10,6 +10,7 @@ API (pure functions):
   init(cfg, key)                                -> params
   init_cache(cfg, batch, s_max)                 -> cache
   forward(params, cfg, batch, mode, ...)        -> (logits, cache, aux)
+  forward(..., with_stats=True)                 -> (logits, cache, aux, stats)
 """
 from __future__ import annotations
 
@@ -20,6 +21,8 @@ import jax.numpy as jnp
 
 from . import blocks as B
 from . import layers as L
+from . import moe as MoE
+from . import sharding as Sh
 
 
 def _stack(trees):
@@ -95,14 +98,25 @@ def _encode(params, cfg, frames, target=None):
 
 def forward(params, cfg, batch, *, mode: str, cache=None,
             lengths: Optional[jnp.ndarray] = None, sp_spec=None,
-            target=None):
-    """Returns (logits, new_cache, aux_loss).
+            target=None, valid: Optional[jnp.ndarray] = None,
+            logits_at: Optional[jnp.ndarray] = None,
+            with_stats: bool = False, dropless: Optional[bool] = None):
+    """Returns (logits, new_cache, aux_loss), and with ``with_stats`` a
+    fourth value: ``{"expert_tokens": (E,), "dropped": (),
+    "experts_touched": ()}``, the MoE layers' live assignments per expert,
+    assignments dropped and experts given any, summed over layers.
 
     ``target`` pins every attention/ssd lowering selection in this
     forward to an explicit machine model, so a multi-backend server can
     mix targets per request instead of relying on the ambient
-    thread-scoped target.
+    thread-scoped target.  ``valid``:(B, S) marks the live tokens (None =
+    all); it only selects what the stats count.  ``logits_at``:(B,) asks
+    for each row's logits at that token position alone: (B, 1, V).
+    ``dropless`` (None = every mode but 'train') keeps every MoE
+    assignment; otherwise the capacity dispatch drops past capacity.
     """
+    if dropless is None:
+        dropless = mode != "train"
     prefix, unit, reps, rem = cfg.pattern_unit()
     x, positions = _embed_inputs(params, cfg, batch, mode, lengths)
     memory = None
@@ -110,8 +124,9 @@ def forward(params, cfg, batch, *, mode: str, cache=None,
         memory = _encode(params, cfg, batch["frames"], target=target)
     ctx = B.Ctx(cfg=cfg, mode=mode, positions=positions, lengths=lengths,
                 memory=memory, emb0=x if cfg.shared_attn_every else None,
-                shared=params.get("shared"), target=target)
-    aux = jnp.zeros((), jnp.float32)
+                shared=params.get("shared"), target=target, valid=valid,
+                dropless=dropless)
+    aux = B.no_aux(cfg)
     new_cache = {"prefix": [], "unit": [], "rem": []}
 
     def constrain(h):
@@ -123,51 +138,66 @@ def forward(params, cfg, batch, *, mode: str, cache=None,
         c = None if cache is None else cache["prefix"][i]
         x, c, a = B.block_apply(kind, params["prefix"][i], x, c, ctx)
         new_cache["prefix"].append(c)
-        aux = aux + a
+        aux = _add(aux, a)
 
     if reps:
         unit_params = tuple(params["unit"])
+        experts = (None,) * len(unit)
+        if dropless and Sh.current_mesh() is None:
+            # the routed experts stay stacked outside the scan: the grouped
+            # products read them in place (a scanned slice is a copy)
+            unit_params, experts = zip(*map(MoE.split_stacked, unit_params))
         unit_cache = tuple(cache["unit"]) if cache is not None else \
             tuple(None for _ in unit)
 
         def body(carry, xs):
             h, a = carry
-            ps, cs = xs
+            ps, cs, layer = xs
             h = constrain(h)
-            from . import sharding as Sh
-            ps = tuple(Sh.gather_layer_params(p, cfg) for p in ps)
+            ps = tuple(MoE.join_stacked(Sh.gather_layer_params(p, cfg), e,
+                                        layer)
+                       for p, e in zip(ps, experts))
             ncs = []
             for j, kind in enumerate(unit):
                 h, cj, aj = B.block_apply(kind, ps[j], h,
                                           None if cs is None else cs[j], ctx)
                 ncs.append(cj)
-                a = a + aj
+                a = _add(a, aj)
             return (h, a), tuple(ncs)
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
-        xs = (unit_params, unit_cache if cache is not None else None)
+        layers = jnp.arange(reps)
         if cache is None:
             (x, aux), _ = jax.lax.scan(
-                lambda c, p: (body_fn(c, (p, None))[0], None),
-                (x, aux), unit_params)
+                lambda c, p: (body_fn(c, (p[0], None, p[1]))[0], None),
+                (x, aux), (unit_params, layers))
             new_cache["unit"] = []
         else:
             (x, aux), ncache = jax.lax.scan(body_fn, (x, aux),
-                                            (unit_params, unit_cache))
+                                            (unit_params, unit_cache, layers))
             new_cache["unit"] = list(ncache)
 
     for i, kind in enumerate(rem):
         c = None if cache is None else cache["rem"][i]
         x, c, a = B.block_apply(kind, params["rem"][i], x, c, ctx)
         new_cache["rem"].append(c)
-        aux = aux + a
+        aux = _add(aux, a)
 
     x = L.norm_apply(params["final_norm"], x, cfg.norm)
     if cfg.family == "vlm" and mode != "decode":
         x = x[:, -batch["tokens"].shape[1]:]     # logits on token positions
+    if logits_at is not None:
+        x = x[jnp.arange(x.shape[0]), logits_at][:, None]
     logits = L.head_apply(params["embed"] if cfg.tie_embeddings else
                           {**params["embed"]}, x, cfg)
-    return logits, (new_cache if cache is not None else None), aux
+    out = (logits, (new_cache if cache is not None else None), aux["lb"])
+    if with_stats:
+        out += ({k: v for k, v in aux.items() if k != "lb"},)
+    return out
+
+
+def _add(a, b):
+    return jax.tree.map(jnp.add, a, b)
 
 
 def count_params(params) -> int:

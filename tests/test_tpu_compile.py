@@ -5,9 +5,10 @@ and scratch sizes that the TPU compiler refuses.  These tests lower each
 Pallas kernel of the main paths with ``interpret=False`` and compile it
 for one chip of a described ``v5e:2x2`` topology, at the shapes the
 chip runs: the ten XNNPACK conversions at the Figure-2 workload shapes,
-flash and decode attention at gemma2-2b serving shapes, ssd at
-mamba2-1.3b shapes, and the batched program ``PortEngine`` builds for
-corpus kernels on rvv-1024.
+flash and decode attention at gemma2-2b serving shapes, flash attention
+at deepseek-v2-lite's latent-attention widths and the grouped product of
+its dropless MoE, ssd at mamba2-1.3b shapes, and the batched program
+``PortEngine`` builds for corpus kernels on rvv-1024.
 
 The topology is described inside a fixture, never while the module is
 imported: only the test worker that runs this file may load the TPU
@@ -84,6 +85,35 @@ def test_flash_attention_gemma2_prefill(one_chip):
                               sharding=one_chip)
     _compile(lambda q, k, v: flash_attention.flash_attention(
         q, k, v, causal=True, window=4096, softcap=50.0), q, kv, kv)
+
+
+def test_flash_attention_mla_prefill(one_chip, on_chip):
+    """deepseek-v2-lite prefill through the registered lowering: 16 heads,
+    query and key head 192 over a value head of 128, which it zero-pads to
+    the query's width."""
+    q = jax.ShapeDtypeStruct((2, 2048, 16, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 2048, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    fn = REGISTRY.lowering("attention", "pallas").fn
+    _compile(lambda q, k, v: fn(q, k, v, True, None, None, 192 ** -0.5),
+             q, q, v)
+
+
+def test_grouped_gemm_moe_block(one_chip):
+    """The dropless MoE's grouped product at deepseek-v2-lite widths: one
+    block of 16,384 tokens x 6 assignments over 64 experts of 2,048 x
+    1,408 lowers to XLA's ragged dot, not a dense product per expert."""
+    x = jax.ShapeDtypeStruct((16384 * 6, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((64, 2048, 1408), jnp.bfloat16,
+                             sharding=one_chip)
+    gs = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+    c = jax.jit(ops.grouped_gemm).lower(x, w, gs).compile()
+    assert "ragged-dot" in c.as_text()
+    cost = c.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["flops"] < 1.1 * 2 * 16384 * 6 * 2048 * 1408
 
 
 def test_decode_attention_gemma2(one_chip):
