@@ -6,17 +6,55 @@ Cache layouts (static shapes; ``lengths`` tracks the valid prefix):
   gqa local  : ring buffer of ``window`` slots (slot = pos % window);
                softmax is permutation-invariant over kv so slot order is
                irrelevant once keys carry RoPE.
-  mla        : c_kv (B, S_max, kv_lora), k_rope (B, S_max, rope_dim) —
-               decode uses the *absorbed* form (q into W_uk, out through
-               W_uv) so the compressed cache is attended directly.
+  mla        : kv (B, S_max, W), one row a position: c_kv (kv_lora),
+               k_rope (rope_dim), zeros up to W, a whole number of lane
+               tiles — decode uses the *absorbed* form (q into W_uk, out
+               through W_uv) so the compressed cache is attended directly.
+
+Inside the model's scan over layers (``layer`` given) a cache arrives as
+the stack of every scanned layer's, (L, B, ...): a step writes only its
+new entries into the layer's slice, in place, and reads the slice where
+it lies (:func:`cache_write`, :func:`cache_view`).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.vtypes import round_up
 from repro.kernels import ops
 from . import layers as L
+
+
+def cache_view(buf, layer):
+    """This layer's entries of ``buf``: the buffer itself, or (``layer``
+    given) its slice of the stack of every scanned layer's."""
+    if layer is None:
+        return buf
+    return jax.lax.dynamic_index_in_dim(buf, layer, keepdims=False)
+
+
+def cache_write(buf, layer, value, index=None):
+    """``buf`` with ``value`` written into this layer's entries (its slice
+    ``layer`` of a stack) and nothing else moved, so that the update
+    aliases the buffer: at ``index``, per-row integer arrays over the
+    leading axes (an index past the end writes nothing), or else from the
+    start of every axis."""
+    if index is not None:
+        index = index if layer is None else (layer,) + index
+        return buf.at[index].set(value, mode="drop")
+    start = (0,) * value.ndim
+    if layer is not None:
+        value, start = value[None], (layer,) + start
+    return jax.lax.dynamic_update_slice(buf, value.astype(buf.dtype), start)
+
+
+def _entry_index(slot, valid, slots):
+    """Each row's entry at ``slot``:(B,), or past the end (no write) for a
+    row that ``valid``:(B, 1) marks inert."""
+    if valid is not None:
+        slot = jnp.where(valid[:, 0], slot, slots)
+    return jnp.arange(slot.shape[0]), slot
 
 
 # ---------------------------------------------------------------------------
@@ -48,10 +86,14 @@ def gqa_cache_init(cfg, batch, s_max, window=None, dtype=None):
 
 
 def gqa_apply(params, x, cfg, *, positions, mode, cache=None, lengths=None,
-              window=None, memory=None, causal=True, target=None):
+              window=None, memory=None, causal=True, target=None,
+              layer=None, valid=None):
     """x:(B,S,d).  mode in train|prefill|decode.  memory: cross-attn kv.
     ``target`` pins the attention lowering selection to an explicit
-    machine model (per-request multi-backend serving)."""
+    machine model (per-request multi-backend serving).  ``layer``: the
+    cache is the stack of the scanned layers' and this is this layer's
+    index in it.  ``valid``:(B, 1) at decode marks the live rows; an
+    inert row writes no entry."""
     b, s, _ = x.shape
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = L.linear(params["wq"], x).reshape(b, s, h, hd)
@@ -79,29 +121,29 @@ def gqa_apply(params, x, cfg, *, positions, mode, cache=None, lengths=None,
         return L.linear_rp(params["wo"], out.reshape(b, s, h * hd), cfg), cache
 
     if mode == "prefill":
-        slots = cache["k"].shape[1]
+        slots = cache["k"].shape[-3]
         if window and slots < s:  # ring: keep the last ``window`` positions
             # write positions p in [s-slots, s) at slot p % slots
             ppos = jnp.arange(s - slots, s)
-            cache = {
-                "k": cache["k"].at[:, ppos % slots].set(k[:, s - slots:]),
-                "v": cache["v"].at[:, ppos % slots].set(v[:, s - slots:]),
-            }
+            idx = (jnp.arange(b)[:, None], (ppos % slots)[None])
+            kw, vw = k[:, s - slots:], v[:, s - slots:]
         else:
-            cache = {"k": cache["k"].at[:, :s].set(k),
-                     "v": cache["v"].at[:, :s].set(v)}
+            idx, kw, vw = None, k, v
+        cache = {"k": cache_write(cache["k"], layer, kw, idx),
+                 "v": cache_write(cache["v"], layer, vw, idx)}
         out = ops.attention(q, k, v, causal=True, window=window,
                             softcap=cfg.softcap, target=target)
         return L.linear_rp(params["wo"], out.reshape(b, s, h * hd), cfg), cache
 
     # decode: s == 1, write at pos = lengths (per row), attend valid prefix
-    slots = cache["k"].shape[1]
+    slots = cache["k"].shape[-3]
     slot = (lengths % slots) if window else lengths
-    bidx = jnp.arange(b)
-    cache = {"k": cache["k"].at[bidx, slot].set(k[:, 0]),
-             "v": cache["v"].at[bidx, slot].set(v[:, 0])}
-    valid = jnp.minimum(lengths + 1, slots)
-    out = ops.decode_attention(q, cache["k"], cache["v"], valid,
+    idx = _entry_index(slot, valid, slots)
+    cache = {"k": cache_write(cache["k"], layer, k[:, 0], idx),
+             "v": cache_write(cache["v"], layer, v[:, 0], idx)}
+    out = ops.decode_attention(q, cache_view(cache["k"], layer),
+                               cache_view(cache["v"], layer),
+                               jnp.minimum(lengths + 1, slots),
                                softcap=cfg.softcap, target=target)
     return L.linear_rp(params["wo"], out.reshape(b, s, h * hd), cfg), cache
 
@@ -132,10 +174,25 @@ def mla_init(key, cfg, d_in=None):
     return p
 
 
+# lanes of a TPU vreg tile.  The latent cache row is a whole number of
+# them: XLA:TPU lays a narrower cache (a 64-wide rope key on its own)
+# out position-minor, where one position's write wants it lane-minor, and
+# then relays it out around every step.
+LANES = 128
+
+
 def mla_cache_init(cfg, batch, s_max, dtype=None):
     dt = dtype or L.dtype_of(cfg)
-    return {"c_kv": jnp.zeros((batch, s_max, cfg.kv_lora_rank), dt),
-            "k_rope": jnp.zeros((batch, s_max, cfg.qk_rope_dim), dt)}
+    width = round_up(cfg.kv_lora_rank + cfg.qk_rope_dim, LANES)
+    return {"kv": jnp.zeros((batch, s_max, width), dt)}
+
+
+def _mla_row(c_kv, k_rope, width):
+    """(..., width): ``c_kv``, then ``k_rope``, then zeros — a latent
+    cache row, or a query laid out against one."""
+    pad = width - c_kv.shape[-1] - k_rope.shape[-1]
+    return jnp.concatenate(
+        [c_kv, k_rope, jnp.zeros(c_kv.shape[:-1] + (pad,), c_kv.dtype)], -1)
 
 
 def mla_softmax_scale(cfg) -> float:
@@ -179,13 +236,15 @@ def _mla_ckv(params, x, cfg, positions):
 
 
 def mla_apply(params, x, cfg, *, positions, mode, cache=None, lengths=None,
-              target=None, **_):
+              target=None, layer=None, valid=None, **_):
     with jax.named_scope("mla"):
         return _mla_apply(params, x, cfg, positions=positions, mode=mode,
-                          cache=cache, lengths=lengths, target=target)
+                          cache=cache, lengths=lengths, target=target,
+                          layer=layer, valid=valid)
 
 
-def _mla_apply(params, x, cfg, *, positions, mode, cache, lengths, target):
+def _mla_apply(params, x, cfg, *, positions, mode, cache, lengths, target,
+               layer, valid):
     b, s, _ = x.shape
     h = cfg.n_heads
     r, nd, vd = cfg.qk_rope_dim, cfg.qk_nope_dim, cfg.v_head_dim
@@ -203,27 +262,28 @@ def _mla_apply(params, x, cfg, *, positions, mode, cache, lengths, target):
         out = ops.attention(q, k, v, causal=True, scale=scale,
                             target=target)
         if mode == "prefill":
-            cache = {"c_kv": cache["c_kv"].at[:, :s].set(c_kv),
-                     "k_rope": cache["k_rope"].at[:, :s].set(k_rope)}
+            row = _mla_row(c_kv, k_rope, cache["kv"].shape[-1])
+            cache = {"kv": cache_write(cache["kv"], layer, row)}
         return L.linear_rp(params["wo"], out.reshape(b, s, h * vd), cfg), cache
 
-    # decode: absorbed attention over the compressed cache
+    # decode: absorbed attention over the compressed cache; both products
+    # read whole cache rows (a slice of one would be copied)
+    slots, width = cache["kv"].shape[-2:]
     c_kv_new, k_rope_new = _mla_ckv(params, x, cfg, positions)
-    bidx = jnp.arange(b)
-    cache = {"c_kv": cache["c_kv"].at[bidx, lengths].set(c_kv_new[:, 0]),
-             "k_rope": cache["k_rope"].at[bidx, lengths].set(k_rope_new[:, 0])}
-    c_kv, k_rope = cache["c_kv"], cache["k_rope"]
+    cache = {"kv": cache_write(cache["kv"], layer,
+                               _mla_row(c_kv_new, k_rope_new, width)[:, 0],
+                               _entry_index(lengths, valid, slots))}
+    kv = cache_view(cache["kv"], layer).astype(jnp.float32)
     w_uk = params["w_uk"].reshape(cfg.kv_lora_rank, h, nd)
     # absorb: q_eff[h] = q_nope[h] @ W_uk[:, h, :].T  -> kv_lora dims
     q_eff = jnp.einsum("bqhn,rhn->bqhr", q_nope.astype(jnp.float32),
                        w_uk.astype(jnp.float32))
-    logits = (jnp.einsum("bqhr,bkr->bhqk", q_eff, c_kv.astype(jnp.float32)) +
-              jnp.einsum("bqhr,bkr->bhqk", q_rope.astype(jnp.float32),
-                         k_rope.astype(jnp.float32))) * scale
-    kpos = jnp.arange(c_kv.shape[1])[None, None, None, :]
+    q_row = _mla_row(q_eff, q_rope.astype(jnp.float32), width)
+    logits = jnp.einsum("bqhr,bkr->bhqk", q_row, kv) * scale
+    kpos = jnp.arange(kv.shape[1])[None, None, None, :]
     logits = jnp.where(kpos <= lengths[:, None, None, None], logits, -1e30)
     p_attn = jax.nn.softmax(logits, axis=-1)
-    ctx = jnp.einsum("bhqk,bkr->bqhr", p_attn, c_kv.astype(jnp.float32))
+    ctx = jnp.einsum("bhqk,bkr->bqhr", p_attn, kv)[..., :cfg.kv_lora_rank]
     w_uv = params["w_uv"].reshape(cfg.kv_lora_rank, h, vd)
     out = jnp.einsum("bqhr,rhv->bqhv", ctx, w_uv.astype(jnp.float32))
     out = out.astype(x.dtype).reshape(b, s, h * vd)

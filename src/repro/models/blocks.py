@@ -3,6 +3,13 @@
 Kinds: attn | local | moe | moe_dense | mamba | mamba_shared | enc | dec.
 Blocks are pure functions over (params, x, ctx) where ctx carries mode,
 positions, lengths, encoder memory and the zamba shared-block closure.
+
+Inside the model's scan over layers a block's cache is split
+(:func:`split_indexed`): the caches indexed by position (attention keys
+and values, the latent cache, cross-attention memory) stay stacked over
+the scanned layers and the block writes its entries into its slice
+(``ctx.layer``) in place; a recurrent state is handed over per layer and
+rewritten whole.
 """
 from __future__ import annotations
 
@@ -31,16 +38,19 @@ class Ctx:
                                    # multi-backend serving); None = ambient
     valid: Optional[jnp.ndarray] = None     # (B, S) live tokens; None = all
     dropless: bool = False         # MoE keeps every assignment (serving)
+    layer: Optional[jnp.ndarray] = None     # this layer in stacked caches
 
 
 def no_aux(cfg):
     """A block's auxiliary outputs, summed over layers by the model: the
     MoE load-balance loss, live assignments per expert, assignments
-    dropped and experts given any (zeros for blocks without experts)."""
+    dropped and experts given any (zeros for blocks without experts), and
+    whether a decode step wrote the block's position-indexed cache."""
     return {"lb": jnp.zeros((), jnp.float32),
             "expert_tokens": jnp.zeros((cfg.n_experts,), jnp.int32),
             "dropped": jnp.zeros((), jnp.int32),
-            "experts_touched": jnp.zeros((), jnp.int32)}
+            "experts_touched": jnp.zeros((), jnp.int32),
+            "cache_writes": jnp.zeros((), jnp.int32)}
 
 
 def _attn_impl(cfg):
@@ -84,7 +94,8 @@ def _tblock_apply(params, x, cache, ctx: Ctx, *, ffn: str, window=None):
     h = L.norm_apply(params["ln1"], x, cfg.norm)
     h, cache = apply(params["attn"], h, cfg, positions=ctx.positions,
                      mode=ctx.mode, cache=cache, lengths=ctx.lengths,
-                     window=window, target=ctx.target)
+                     window=window, target=ctx.target, layer=ctx.layer,
+                     valid=ctx.valid)
     if cfg.sandwich_norm:
         h = L.norm_apply(params["ln1p"], h, cfg.norm)
     x = x + h
@@ -94,7 +105,7 @@ def _tblock_apply(params, x, cache, ctx: Ctx, *, ffn: str, window=None):
         h, lb, st = M.moe_apply(params["ffn"], h, cfg,
                                 dropless=ctx.dropless, valid=ctx.valid,
                                 stats=True)
-        aux = {"lb": lb, **st}
+        aux = {**aux, "lb": lb, **st}
     else:
         with jax.named_scope("dense_mlp"):
             h = L.mlp_apply(params["ffn"], h, cfg)
@@ -138,7 +149,8 @@ def _shared_apply(shared, x, cache, ctx: Ctx):
     h = L.norm_apply(shared["ln1"], cat, cfg.norm)
     h, cache = A.gqa_apply(shared["attn"], h, cfg, positions=ctx.positions,
                            mode=ctx.mode, cache=cache, lengths=ctx.lengths,
-                           target=ctx.target)
+                           target=ctx.target, layer=ctx.layer,
+                           valid=ctx.valid)
     x = x + h
     m = L.mlp_apply(shared["mlp"],
                     L.norm_apply(shared["ln2"], cat, cfg.norm), cfg)
@@ -202,12 +214,13 @@ def _dec_apply(params, x, cache, ctx: Ctx):
     h, self_cache = A.gqa_apply(params["attn"], h, cfg,
                                 positions=ctx.positions, mode=ctx.mode,
                                 cache=None if cache is None else cache["self"],
-                                lengths=ctx.lengths, target=ctx.target)
+                                lengths=ctx.lengths, target=ctx.target,
+                                layer=ctx.layer, valid=ctx.valid)
     x = x + h
-    # cross attention over encoder memory
+    # cross attention over encoder memory (cached at prefill, read at decode)
     h = L.norm_apply(params["lnx"], x, cfg.norm)
     if ctx.mode == "decode":
-        xk, xv = cache["xk"], cache["xv"]
+        xk, xv = (A.cache_view(cache[n], ctx.layer) for n in ("xk", "xv"))
     else:
         mem = ctx.memory  # (B, F, d) encoder output
         f = mem.shape[1]
@@ -221,7 +234,10 @@ def _dec_apply(params, x, cache, ctx: Ctx):
     h = L.norm_apply(params["ln2"], x, cfg.norm)
     x = x + L.mlp_apply(params["mlp"], h, cfg)
     if cache is not None:
-        cache = {"self": self_cache, "xk": xk, "xv": xv}
+        cache = {**cache, "self": self_cache}
+        if ctx.mode != "decode":
+            cache.update((n, A.cache_write(cache[n], ctx.layer, m))
+                         for n, m in (("xk", xk), ("xv", xv)))
     return x, cache, no_aux(cfg)
 
 
@@ -263,7 +279,36 @@ def block_cache_init(kind, cfg, batch, s_max):
     raise ValueError(kind)
 
 
+def split_indexed(kind, cache):
+    """(indexed, rest) of a ``kind`` block's cache: the part indexed by
+    position, which a step writes entries of in place, and the recurrent
+    state, which it rewrites whole (None where there is none)."""
+    if cache is None or kind == "mamba":
+        return None, cache
+    if kind == "mamba_shared":
+        return cache["attn"], cache["mamba"]
+    return cache, None
+
+
+def join_indexed(kind, indexed, rest):
+    """The cache that :func:`split_indexed` split."""
+    if kind == "mamba":
+        return rest
+    if kind == "mamba_shared":
+        return None if rest is None else {"mamba": rest, "attn": indexed}
+    return indexed
+
+
 def block_apply(kind, params, x, cache, ctx: Ctx):
+    """(x, cache, aux) of a ``kind`` block; ``aux["cache_writes"]`` is 1
+    where a decode step wrote entries of a position-indexed cache."""
+    x, cache, aux = _block_apply(kind, params, x, cache, ctx)
+    if ctx.mode == "decode" and split_indexed(kind, cache)[0] is not None:
+        aux = {**aux, "cache_writes": jnp.ones((), jnp.int32)}
+    return x, cache, aux
+
+
+def _block_apply(kind, params, x, cache, ctx: Ctx):
     if kind == "attn":
         return _tblock_apply(params, x, cache, ctx, ffn="dense")
     if kind == "local":

@@ -14,6 +14,7 @@ API (pure functions):
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, Optional
 
 import jax
@@ -103,8 +104,10 @@ def forward(params, cfg, batch, *, mode: str, cache=None,
             with_stats: bool = False, dropless: Optional[bool] = None):
     """Returns (logits, new_cache, aux_loss), and with ``with_stats`` a
     fourth value: ``{"expert_tokens": (E,), "dropped": (),
-    "experts_touched": ()}``, the MoE layers' live assignments per expert,
-    assignments dropped and experts given any, summed over layers.
+    "experts_touched": (), "cache_writes": ()}``, the MoE layers' live
+    assignments per expert, assignments dropped and experts given any,
+    summed over layers, and the layers whose position-indexed cache a
+    decode step wrote its new entries into in place.
 
     ``target`` pins every attention/ssd lowering selection in this
     forward to an explicit machine model, so a multi-backend server can
@@ -149,33 +152,34 @@ def forward(params, cfg, batch, *, mode: str, cache=None,
             unit_params, experts = zip(*map(MoE.split_stacked, unit_params))
         unit_cache = tuple(cache["unit"]) if cache is not None else \
             tuple(None for _ in unit)
+        # the position-indexed caches ride the carry whole, each layer
+        # writing its entries into its own slice in place; a recurrent
+        # state is scanned over and rewritten whole (xs -> ys)
+        held, states = zip(*map(B.split_indexed, unit, unit_cache))
 
         def body(carry, xs):
-            h, a = carry
-            ps, cs, layer = xs
+            h, a, held = carry
+            ps, states, layer = xs
             h = constrain(h)
             ps = tuple(MoE.join_stacked(Sh.gather_layer_params(p, cfg), e,
                                         layer)
                        for p, e in zip(ps, experts))
-            ncs = []
+            lctx = dataclasses.replace(ctx, layer=layer)
+            out = []
             for j, kind in enumerate(unit):
-                h, cj, aj = B.block_apply(kind, ps[j], h,
-                                          None if cs is None else cs[j], ctx)
-                ncs.append(cj)
+                h, cj, aj = B.block_apply(
+                    kind, ps[j], h, B.join_indexed(kind, held[j], states[j]),
+                    lctx)
+                out.append(B.split_indexed(kind, cj))
                 a = _add(a, aj)
-            return (h, a), tuple(ncs)
+            held, states = zip(*out)
+            return (h, a, held), states
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
-        layers = jnp.arange(reps)
-        if cache is None:
-            (x, aux), _ = jax.lax.scan(
-                lambda c, p: (body_fn(c, (p[0], None, p[1]))[0], None),
-                (x, aux), (unit_params, layers))
-            new_cache["unit"] = []
-        else:
-            (x, aux), ncache = jax.lax.scan(body_fn, (x, aux),
-                                            (unit_params, unit_cache, layers))
-            new_cache["unit"] = list(ncache)
+        (x, aux, held), states = jax.lax.scan(
+            body_fn, (x, aux, held), (unit_params, states, jnp.arange(reps)))
+        if cache is not None:
+            new_cache["unit"] = list(map(B.join_indexed, unit, held, states))
 
     for i, kind in enumerate(rem):
         c = None if cache is None else cache["rem"][i]

@@ -1,22 +1,29 @@
 """Serving engine: batched prefill + decode over static-shape caches.
 
-The engine owns a fixed-capacity request batch (continuous batching at
-slot granularity): prefill fills a slot's cache, decode advances every
-active slot one token per step (one ``serve_step`` — the function the
-decode-shape dry-run cells lower).  Greedy or temperature sampling.
+The engine owns a fixed-capacity request batch: prefill fills every
+row's cache, decode advances every row one token per step (one
+``serve_step`` — the function the decode-shape dry-run cells lower).
+Greedy or temperature sampling.
+
+Both steps donate the cache.  A cache indexed by position (attention
+keys and values, the latent cache) is read where it lies and receives
+only its new entries, in place, inside the model's scan over layers; a
+recurrent state is carried and rewritten whole
+(``models/blocks.split_indexed``).
 
 Rows may hold prompts of different lengths, right-padded: the prefill
 takes each row's logits at its own last token and decode writes each
 row's cache at its own length.  A row of length 0 is inert: it runs with
-the batch and counts as no work.  A family whose cache is a recurrent
-state (ssm, hybrid) would take the pad tokens into that state, so it
-refuses rows of different lengths.
+the batch, counts as no work and writes no cache entry at decode.  A
+family whose cache is a recurrent state (ssm, hybrid) would take the pad
+tokens into that state, so it refuses rows of different lengths.
 
 Each host-side stage is a ``jax.profiler.TraceAnnotation`` on the device
 trace's clock (no flag, no device sync of its own): ``engine.prefill``
 (args ``rows``, ``width``, ``tokens`` live), ``engine.decode_step``
 (``step``, ``rows`` live) and ``engine.fetch``, the blocking copy of a
-step's tokens to the host.  ``stats()`` returns the counters.
+step's tokens to the host.  ``stats()`` returns the counters, among them
+``decode_cache_writes_in_place``.
 """
 from __future__ import annotations
 
@@ -34,17 +41,22 @@ RECURRENT_FAMILIES = ("ssm", "hybrid")
 
 def _new_counts(cfg):
     """Zeroed running counts that the steps add to (``acc``): live MoE
-    assignments per expert, assignments dropped, and experts given a live
-    assignment (decode steps only)."""
+    assignments per expert, assignments dropped, and (decode steps only)
+    experts given a live assignment and layers whose cache took its new
+    entries in place."""
     return {"expert_tokens": jnp.zeros((cfg.n_experts,), jnp.int32),
             "dropped": jnp.zeros((), jnp.int32),
-            "decode_experts_touched": jnp.zeros((), jnp.int32)}
+            "decode_experts_touched": jnp.zeros((), jnp.int32),
+            "decode_cache_writes": jnp.zeros((), jnp.int32)}
 
 
-def _add_counts(acc, st, touched=0):
+def _add_counts(acc, st, decode=False):
     return {"expert_tokens": acc["expert_tokens"] + st["expert_tokens"],
             "dropped": acc["dropped"] + st["dropped"],
-            "decode_experts_touched": acc["decode_experts_touched"] + touched}
+            "decode_experts_touched": acc["decode_experts_touched"] +
+            (st["experts_touched"] if decode else 0),
+            "decode_cache_writes": acc["decode_cache_writes"] +
+            (st["cache_writes"] if decode else 0)}
 
 
 def make_prefill_step(cfg, target=None, dropless=True):
@@ -88,8 +100,7 @@ def make_serve_step(cfg, target=None, dropless=True):
             with_stats=True, dropless=dropless)
         if acc is None:
             return logits[:, 0], cache
-        return logits[:, 0], cache, _add_counts(acc, st,
-                                                st["experts_touched"])
+        return logits[:, 0], cache, _add_counts(acc, st, decode=True)
     return serve_step
 
 
@@ -184,15 +195,19 @@ class Engine:
         """Counters since the engine was built: live and padded prefill
         tokens, decode steps, live rows summed over decode steps, live
         MoE assignments per expert (summed over layers and steps),
-        assignments dropped (0 on the serving path), and experts given a
-        live assignment, summed over MoE layers and decode steps.  Reading
-        the MoE counts waits for the device."""
+        assignments dropped (0 on the serving path), experts given a live
+        assignment, summed over MoE layers and decode steps, and layers
+        whose position-indexed cache a decode step wrote its new entries
+        into in place, summed over steps (0 for a recurrent state).
+        Reading the device's counts waits for the device."""
         out: Dict[str, Any] = dict(self._counts)
         out["moe_expert_tokens"] = np.asarray(
             self._acc["expert_tokens"]).astype(np.int64)
         out["moe_dropped"] = int(self._acc["dropped"])
         out["decode_experts_touched"] = int(
             self._acc["decode_experts_touched"])
+        out["decode_cache_writes_in_place"] = int(
+            self._acc["decode_cache_writes"])
         return out
 
     def _sample(self, logits):

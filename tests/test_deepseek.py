@@ -426,3 +426,88 @@ def test_rope_columns_give_the_reference_the_same_rotation():
     k3 = ref._apply_rope((x @ wkv)[:, None, kvr:], cos, sin)
     bad = np.einsum("qhd,khd->hqk", q3, jnp.broadcast_to(k3, (6, h, r)))
     assert not np.allclose(bad, want, rtol=1e-2, atol=1e-1)
+
+
+# --- (g) a decode step writes its new cache entries in place -----------------
+
+CACHE_CASES = {
+    "mla": _tiny,
+    "gqa": lambda: get_config("mistral-large-123b").reduced().replace(
+        dtype="float32"),
+    # local layers keep a ring of 8 slots, which the decode steps wrap
+    "gqa-ring": lambda: get_config("gemma2-2b").reduced().replace(
+        dtype="float32", window=8),
+}
+
+
+def _stacked_leaves(cache):
+    """Every cache leaf as a host array (L, B, P, ...): the scanned
+    layers' stacks as they are, a lone layer's with a leading axis."""
+    out = []
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        stacked = any(getattr(p, "key", None) == "unit" for p in path)
+        out.append(np.array(leaf if stacked else leaf[None]))
+    return out
+
+
+def _logits_reference(params, cfg, seq, at):
+    if cfg.attn_kind == "mla":
+        return _reference(params, cfg, seq, at)
+    logits = M.forward(params, cfg, {"tokens": jnp.asarray(seq)[None]},
+                       mode="train")[0]
+    return np.asarray(logits[0, np.asarray(at)])
+
+
+@pytest.mark.parametrize("case", sorted(CACHE_CASES))
+def test_decode_writes_only_its_new_entries(case):
+    """Ragged rows and an inert one through prefill and six decode steps:
+    the logits are the float32 reference's; each entry a step wrote is
+    what a prefill of the same tokens writes; every other entry, the
+    inert row's among them, is bitwise what the prefill left; and each
+    step counts every layer's write as in place."""
+    cfg = CACHE_CASES[case]()
+    params = jax.jit(M.init, static_argnums=0)(cfg, jax.random.PRNGKey(11))
+    rng = np.random.default_rng(11)
+    lens, steps = np.array([5, 8, 2, 0]), 6
+    prompts = np.zeros((4, 8), np.int32)
+    for r, n in enumerate(lens):
+        prompts[r, :n] = rng.integers(0, cfg.vocab_size, n)
+    eng = Engine(cfg, params, max_batch=4, max_seq=16)
+    first = eng.prefill(prompts, lens)
+    before = _stacked_leaves(eng.cache)
+    logits = {}
+    toks = eng.decode(first, steps, on_step=lambda i: logits.__setitem__(
+        i, np.asarray(eng.logits)))
+    after = _stacked_leaves(eng.cache)
+    gen = np.concatenate([np.asarray(first)[:, None], toks], 1)
+    written = [np.zeros(a.shape[1:3], bool) for a in after]
+    for r, n in enumerate(lens):
+        if n == 0:
+            continue
+        seq = np.concatenate([prompts[r, :n], gen[r, :steps]])
+        want = _logits_reference(params, cfg, seq, [n + i
+                                                    for i in range(steps)])
+        for i in range(steps):
+            assert ref.rel_err(logits[i][r, :cfg.vocab_size], want[i]) < 1e-4
+        one = M.init_cache(cfg, 1, 16)
+        _, one, _ = M.forward(params, cfg, {"tokens": jnp.asarray(seq)[None]},
+                              mode="prefill", cache=one)
+        for a, o, w in zip(after, _stacked_leaves(one), written):
+            slots = (n + np.arange(steps)) % a.shape[2]
+            w[r, slots] = True
+            np.testing.assert_allclose(a[:, r, slots], o[:, 0, slots],
+                                       rtol=1e-4, atol=1e-5)
+    for a, b, w in zip(after, before, written):
+        assert w.any()
+        np.testing.assert_array_equal(a[:, ~w], b[:, ~w])
+    st = eng.stats()
+    assert st["decode_cache_writes_in_place"] == steps * cfg.n_layers
+
+
+def test_recurrent_state_counts_no_in_place_writes():
+    cfg = get_config("mamba2-1.3b").reduced().replace(dtype="float32")
+    params = M.init(cfg, jax.random.PRNGKey(0))
+    eng = Engine(cfg, params, max_batch=2, max_seq=16)
+    eng.decode(eng.prefill(np.ones((2, 8), np.int32)), 3)
+    st = eng.stats()
+    assert st["decode_steps"] == 3 and st["decode_cache_writes_in_place"] == 0

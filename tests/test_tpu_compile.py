@@ -7,14 +7,17 @@ for one chip of a described ``v5e:2x2`` topology, at the shapes the
 chip runs: the ten XNNPACK conversions at the Figure-2 workload shapes,
 flash and decode attention at gemma2-2b serving shapes, flash attention
 at deepseek-v2-lite's latent-attention widths and the grouped product of
-its dropless MoE, ssd at mamba2-1.3b shapes, and the batched program
-``PortEngine`` builds for corpus kernels on rvv-1024.
+its dropless MoE, ssd at mamba2-1.3b shapes, the batched program
+``PortEngine`` builds for corpus kernels on rvv-1024, and the serving
+engine's whole decode step for deepseek-v2-lite at the model cell's
+shapes.
 
 The topology is described inside a fixture, never while the module is
 imported: only the test worker that runs this file may load the TPU
 compiler library.  All such compiles live in this one file.
 """
 import os
+import re
 import sys
 
 import jax
@@ -189,3 +192,84 @@ def test_chip_width_program_compiles(kernel, dtype, one_chip):
     assert eng._strips[(id(k), tgt)] == \
         targets.get_target("tpu-v5e").vreg_elems(dtype)
     prog.lower(*shapes).compile()
+
+
+_INSTR = re.compile(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\((.*)")
+
+
+def _materialised(txt):
+    """(shape, opcode, fused root opcode) of every array-valued instruction
+    of a compiled module outside fusion bodies: what the step writes to
+    memory.  A fusion's root is its computation's ROOT instruction."""
+    comps, name = {}, None
+    for line in txt.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and line.strip() != "}":
+            comps[name].append(line)
+    fused = {c for lines in comps.values() for ln in lines
+             for c in re.findall(r"calls=%([\w.\-]+)", ln)
+             if re.search(r" fusion\(.*kind=k", ln)}
+    roots = {n: _INSTR.match(next(ln for ln in lines if "ROOT" in ln))
+             for n, lines in comps.items() if n in fused}
+    out = []
+    for n, lines in comps.items():
+        if n in fused:
+            continue
+        for ln in lines:
+            m = _INSTR.match(ln)
+            if not m:
+                continue
+            root = None
+            if m.group(2) == "fusion":
+                body = re.search(r"calls=%([\w.\-]+)", ln).group(1)
+                root = roots[body] and roots[body].group(2)
+            out.append((tuple(int(d) for d in m.group(1).split(",") if d),
+                        m.group(2), root))
+    return out
+
+
+def test_deepseek_decode_step_writes_cache_in_place(one_chip, on_chip):
+    """The engine's decode step (``make_serve_step``, cache donated as
+    ``Engine`` does) for deepseek-v2-lite's first 7 layers at batch 32 and
+    2,304 positions: no copy, slice or update materialises a layer's
+    latent cache or the stack of them (the scan once sliced each layer's
+    out, wrote it back whole and copied the stack, 663,609,856 bytes of
+    temporaries); the one write is the scatter of the new entries into
+    the donated cache.  Lowered under the chip's default policy."""
+    from repro.configs import get_config
+    from repro.models import model as M
+    from repro.serve.engine import _new_counts, make_serve_step
+
+    cfg = get_config("deepseek-v2-lite-16b").replace(n_layers=7)
+    b, s = 32, 2304
+
+    def sds(tree):
+        return jax.tree.map(lambda x: _sds(x, one_chip), tree)
+
+    params = sds(jax.eval_shape(lambda: M.init(cfg, jax.random.PRNGKey(0))))
+    cache = sds(jax.eval_shape(lambda: M.init_cache(cfg, b, s)))
+    acc = sds(jax.eval_shape(lambda: _new_counts(cfg)))
+    tok, lens, live = (jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+                       for shape, dt in (((b, 1), jnp.int32),
+                                         ((b,), jnp.int32),
+                                         ((b,), jnp.bool_)))
+    with REGISTRY.use_policy("pallas"):
+        c = jax.jit(make_serve_step(cfg), donate_argnums=(1, 5)).lower(
+            params, cache, tok, lens, live, acc).compile()
+    mem = c.memory_analysis()
+    assert mem.temp_size_in_bytes < 128 * 2 ** 20
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    widths = {x.shape[-1] for x in jax.tree.leaves(cache)} | {
+        cfg.kv_lora_rank, cfg.qk_rope_dim}
+    cached = [(shape, op, root) for shape, op, root in _materialised(
+        c.as_text()) if len(shape) >= 2 and shape[-2] == s and
+        shape[-1] in widths]
+    in_place = [x for x in cached if "scatter" in (x[1], x[2])]
+    assert len(in_place) == 2          # the lone dense layer's, the stack's
+    assert [x for x in cached if x not in in_place and
+            x[1] not in ("parameter", "get-tuple-element", "bitcast")] == []
