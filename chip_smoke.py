@@ -130,20 +130,19 @@ def phase_port(seed: int) -> None:
 def phase_pallas() -> None:
     import jax
     import numpy as np
-    from benchmarks import xnnpack_suite
     from repro.core.registry import REGISTRY
-    from repro.kernels import ref
+    from repro.kernels import figure2, ref
 
     errs, bad = {}, []
     t0 = time.perf_counter()
-    for name, op, args, kw in xnnpack_suite.workloads():
-        f, arrs = xnnpack_suite.array_fn(REGISTRY.lowering(op, "pallas").fn,
-                                         args, kw)
+    for name, op, args, kw in figure2.workloads():
+        f, arrs = figure2.array_fn(REGISTRY.lowering(op, "pallas").fn,
+                                   args, kw)
         compiled = jax.jit(f).lower(*arrs).compile()
         if "tpu_custom_call" not in compiled.as_text():
             bad.append(f"{name}: no tpu_custom_call")
         got = jax.tree.leaves(compiled(*arrs))
-        r, _ = xnnpack_suite.array_fn(getattr(ref, op), args, kw)
+        r, _ = figure2.array_fn(getattr(ref, op), args, kw)
         with jax.default_matmul_precision("highest"):
             want = jax.tree.leaves(jax.jit(r)(*arrs))
         worst = 0.0

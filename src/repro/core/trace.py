@@ -19,8 +19,9 @@ adopt the same methodology tier for the kernel-level comparison:
     vector libm — the reason the paper's vtanh/vsigmoid baselines are
     slow), used to cross-check the declared models.
 
-Roofline seconds for the full system come from XLA ``cost_analysis()`` of
-the compiled dry-run instead (see benchmarks/roofline.py).
+``repro.kernels.figure2`` builds the paper's Figure-2 columns from these
+counts.  They are counts, not times: device time and roofline shares come
+from the chip benchmark (``bench/``).
 """
 from __future__ import annotations
 
@@ -357,50 +358,6 @@ def _walk(jaxpr, scalarize: bool, union_overhead: bool = False) -> int:
         else:
             total += ovh * vi(n)
     return total
-
-
-def jaxpr_hbm_bytes(fn, *args, **kw) -> int:
-    """HBM traffic of the *unfused* op-by-op translation: every equation
-    reads its operands and writes its output (the SIMDe generic-union
-    semantics — each intrinsic round-trips memory).  Customized kernels
-    pay only their true inputs+outputs; the ratio is the fusion win."""
-    is_arr = [hasattr(a, "shape") and hasattr(a, "dtype") for a in args]
-    arr_args = [a for a, ok in zip(args, is_arr) if ok]
-
-    def wrapper(*traced):
-        it = iter(traced)
-        full = [next(it) if ok else a for a, ok in zip(args, is_arr)]
-        return fn(*full, **kw)
-
-    closed = jax.make_jaxpr(wrapper)(*arr_args)
-    return _walk_bytes(closed.jaxpr)
-
-
-def _nbytes(aval) -> int:
-    if not hasattr(aval, "shape"):
-        return 0
-    n = int(np.prod(aval.shape)) if aval.shape else 1
-    return n * jnp.dtype(getattr(aval, "dtype", jnp.float32)).itemsize
-
-
-def _walk_bytes(jaxpr) -> int:
-    total = 0
-    for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        for sub in _subjaxprs(eqn):
-            total += _trip_count(eqn) * _walk_bytes(sub)
-        if name in _FREE_PRIMS or name in _CTRL_PRIMS:
-            continue
-        total += sum(_nbytes(v.aval) for v in eqn.outvars)
-        total += sum(_nbytes(v.aval) for v in eqn.invars
-                     if hasattr(v, "aval"))
-    return total
-
-
-def io_bytes(*arrays) -> int:
-    """True input+output bytes of a fused kernel."""
-    return sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
-               for a in arrays if hasattr(a, "shape"))
 
 
 def _subjaxprs(eqn):

@@ -8,7 +8,7 @@ preprocessor ladder picks an implementation (DESIGN.md §3).
   policy 'vector' (default on CPU) — whole-array jnp  (original SIMDe)
   policy 'generic'                 — scalar-emulation oracle tier
 
-``repro.core.use_policy`` overrides per scope; benchmarks/xnnpack_suite
+``repro.core.use_policy`` overrides per scope; ``repro.kernels.figure2``
 runs both sides of the paper's Figure-2 comparison through this exact
 dispatch.
 """

@@ -6,7 +6,7 @@ op-by-op, no fusion, fp32 math.  These serve two roles:
 
   1. correctness oracle for the Pallas kernels (tests assert allclose),
   2. the *baseline* side of the paper's Figure-2 comparison
-     (benchmarks/xnnpack_suite.py counts their dynamic instructions).
+     (``repro.kernels.figure2`` counts their dynamic instructions).
 
 The ten functions are the ten XNNPACK microkernels evaluated in the paper
 (§4.2): gemm, convhwc, dwconv, maxpool, argmaxpool, vrelu, vsqrt, vtanh,

@@ -3,10 +3,11 @@ target's VLEN x LMUL.
 
 A kernel ported from NEON walks memory in fixed 128-bit strips — on a
 1024-bit RVV machine it uses an eighth of every register, which is
-exactly SIMDe's fixed-vlen limitation (and why BENCH_port.json's
-rvv-128..1024 columns used to be identical).  This pass rewrites the
-typed SSA IR so the strip consumes one whole register *group* per
-iteration:
+exactly SIMDe's fixed-vlen limitation (and why, in
+``tests/golden/port_counts.json``, ``total_instrs`` mostly stays flat
+from rvv-128 to rvv-1024 while ``revec_instrs`` shrinks).  This pass
+rewrites the typed SSA IR so the strip consumes one whole register
+*group* per iteration:
 
 1. **match** — find top-level strip loops: a counted-down scalar phi
    (``for (; n >= K; n -= K)``) plus affine pointer walks with constant

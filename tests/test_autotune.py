@@ -202,6 +202,67 @@ print(json.dumps(d.to_dict()))
         "reloaded decision differs from the tuned one"
 
 
+# the corpus subset the sweep below calibrates and tunes on; the
+# simulator retires instructions one by one in Python, so tuning runs at
+# a small n: the decisions (LMUL, factor cap, tail policy) are
+# structural and carry to serving sizes
+SUBSET = tuple(c.kernel for c in harness.cases())[:8]
+SUBSET_TARGETS = ("rvv-128", "rvv-1024")
+
+
+@pytest.fixture(scope="module")
+def subset_sweep(tmp_path_factory):
+    """Calibrate on ``SUBSET`` and tune each kernel on both targets into
+    a cache file: ``(calibration, {(kernel, target): decision},
+    {kernel: PortedKernel}, path)``."""
+    cases = {c.kernel: c for c in harness.cases(n=256, tail_n=259)}
+    kernels = {n: port.compile_file(os.path.join(CORPUS, cases[n].file),
+                                    name=n) for n in SUBSET}
+    items = [(kernels[n], cases[n].make_args(np.random.default_rng(i)))
+             for i, n in enumerate(SUBSET)]
+    cal = autotune.calibrate(items)
+    assert cal.factors, "calibration fit no factors"
+    path = str(tmp_path_factory.mktemp("autotune") / "autotune.json")
+    cache = autotune.AutotuneCache(path)
+    cache.set_calibration(cal)
+    decisions = {(k.name, t): cache.tune_or_get(k, args, t,
+                                                calibration=cal)
+                 for k, args in items for t in SUBSET_TARGETS}
+    return cal, decisions, kernels, path
+
+
+@pytest.mark.parametrize("target", SUBSET_TARGETS)
+@pytest.mark.parametrize("name", SUBSET)
+def test_subset_tuned_never_worse_and_survives_reload(subset_sweep, name,
+                                                      target):
+    """Across the subset on both ends of the family: the tuned decision
+    never retires more than static, and a fresh cache reading the
+    persisted file serves the same decision."""
+    _, decisions, kernels, path = subset_sweep
+    d = decisions[(name, target)]
+    assert d.measured is not None and d.static is not None
+    assert d.measured <= d.static, \
+        f"{name}@{target}: tuned retires more than static " \
+        f"({d.measured} > {d.static})"
+    back = autotune.AutotuneCache(path, strict=True).get(kernels[name],
+                                                         target)
+    assert back is not None, \
+        f"{name}@{target}: tuned decision did not survive reload"
+    assert back.to_dict() == d.to_dict()
+
+
+def test_subset_calibration_survives_reload_and_tuning_pays(subset_sweep):
+    cal, decisions, _, path = subset_sweep
+    rcal = autotune.AutotuneCache(path, strict=True).calibration
+    assert rcal is not None and rcal.factors == cal.factors, \
+        "calibration did not survive reload"
+    improved = [n for n in SUBSET
+                if decisions[(n, "rvv-128")].measured
+                < decisions[(n, "rvv-128")].static]
+    assert len(improved) >= min(5, len(SUBSET) - 2), \
+        f"only {improved} improved retired counts on rvv-128"
+
+
 def test_ir_fingerprint_orphans_stale_decisions(tmp_path):
     """Editing a kernel changes its fingerprint: the stale decision is
     simply never found (invalidation by construction, no TTL logic)."""

@@ -241,6 +241,73 @@ class TestChaosLadder:
 
 
 # ---------------------------------------------------------------------------
+# the contract per fault class: what a fault may cost, and what it never may
+# ---------------------------------------------------------------------------
+
+# fault class -> (seam, error, times, expected rung, expected degraded):
+# a veto or a persistent failure always degrades; a transient timeout,
+# an eviction storm or a corrupted cache entry never does
+FAULT_CLASSES = {
+    "revec_veto": ("revec.retile", "RevecVeto", None, "compiled", True),
+    "compile_fail": ("compile.trace", "CompileError", None, "interp",
+                     True),
+    "runtime_fault": ("compile.run", "ExecError", None, "interp", True),
+    "transient_timeout": ("compile.trace", "CompileTimeout", 1,
+                          "compiled+revec", False),
+    "eviction_storm": (None, None, None, "compiled+revec", False),
+    "corrupted_cache": (None, None, None, "compiled+revec", False),
+}
+# an elementwise strip, a reduction and a widening MACC
+CONTRACT_KERNELS = ("xnn_f32_vadd_ukernel", "xnn_f32_vdot_ukernel",
+                    "qs8_vmlal_dot_ukernel")
+CONTRACT_TARGETS = ("rvv-128", "rvv-1024")
+
+
+def _faulted_run(cls, k, args, t):
+    seam, err, times, _, _ = FAULT_CLASSES[cls]
+    if cls == "eviction_storm":
+        with fi.eviction_storm(1):
+            return rz.run_resilient(k, *args, target=t, jit=False)
+    port.compiled_cache_clear()
+    if cls == "corrupted_cache":
+        k.compile(target=t, revec=True, jit=False)
+        assert fi.corrupt_cache_entry(k.fn.name)
+        return rz.run_resilient(k, *args, target=t, jit=False)
+    with fi.injected(seam, error=getattr(rz, err), times=times):
+        return rz.run_resilient(k, *args, target=t, jit=False)
+
+
+@pytest.mark.parametrize("cls", sorted(FAULT_CLASSES))
+def test_fault_class_contract(kernels, cls):
+    """Per fault class, over an elementwise, a reduction and a widening
+    kernel on rvv-128 and rvv-1024: zero silent corruption (bitwise the
+    fault-free output of the rung that served), the expected rung every
+    time, a fallback rate exactly as expected, and a DegradationRecord
+    for every degraded run."""
+    *_, want_rung, want_degraded = FAULT_CLASSES[cls]
+    degraded = []
+    for kname in CONTRACT_KERNELS:
+        k, args = kernels[kname], _args_for(kname)
+        for t in CONTRACT_TARGETS:
+            port.compiled_cache_clear()
+            out, _ = rz.run_resilient(k, *args, target=t, jit=False)
+            ref = {"compiled+revec": out,
+                   "compiled": k.compile(target=t, revec=False,
+                                         jit=False)(*args),
+                   "interp": k(*args, target=t)}
+            rz.reset_resilience()
+            out, rec = _faulted_run(cls, k, args, t)
+            label = f"{cls}: {kname}@{t}"
+            _bitwise_equal(out, ref[rec.used], label)
+            assert rec.used == want_rung, f"{label} served by {rec.used}"
+            if rec.degraded:
+                assert rz.degradation_records(kernel=k.fn.name), \
+                    f"{label}: degraded run left no DegradationRecord"
+            degraded.append(rec.degraded)
+    assert sum(degraded) / len(degraded) == float(want_degraded), degraded
+
+
+# ---------------------------------------------------------------------------
 # circuit breaker
 # ---------------------------------------------------------------------------
 

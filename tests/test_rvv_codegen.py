@@ -229,28 +229,78 @@ def test_golden_emitted_c(name):
 
 
 # ---------------------------------------------------------------------------
-# the RVV side keeps the RVV width
+# retired counts pinned: every kernel x every width, exactly
 # ---------------------------------------------------------------------------
 
-def _committed(name):
+# the retired-count sweep: the rvv-256 column joins the family here
+SIM_SWEEP = ("rvv-64", "rvv-128", "rvv-256", "rvv-512", "rvv-1024")
+# enough strips that per-loop constants amortize and the widths separate
+SIM_N, SIM_TAIL_N = 1024, 1027
+SIM_CASES = {c.kernel: c for c in harness.cases(n=SIM_N, tail_n=SIM_TAIL_N)}
+# fixed-shape counter-example: fold's cross-lane vget_high/low structure
+# stays at NEON granularity, so its retired count must NOT scale with VLEN
+UNSCALABLE = ("fold_halves_f32",)
+
+
+@lru_cache(maxsize=None)
+def _golden(name):
     import json
-    with open(os.path.join(ROOT, name)) as f:
-        return json.load(f)["kernels"]
+    with open(os.path.join(ROOT, "tests", "golden", name)) as f:
+        return json.load(f)
+
+
+@lru_cache(maxsize=None)
+def _sim_counts(name):
+    """The emitted RVV's retired counts on the simulator at every width
+    of ``SIM_SWEEP``, each run checked against the NumPy reference."""
+    case = SIM_CASES[name]
+    args = case.make_args(np.random.default_rng(0))
+    want = case.reference(*args)
+    rows = {}
+    for target in SIM_SWEEP:
+        out, counts = rvv.execute(rvv.emit(_kernel(name), target), *args)
+        _assert_matches(out, want, case, f"{name} on {target} at n={SIM_N}")
+        rows[target] = {
+            "executed": counts["executed"], "vector": counts["vector"],
+            "vsetvli": counts["vsetvli"] + counts["implicit_vsetvli"],
+            "vuops": counts["vuops"]}
+    return rows
+
+
+@pytest.mark.parametrize("kernel", sorted(SIM_CASES))
+def test_retired_counts_match_golden(kernel):
+    """Retired counts are deterministic: any change, up or down, is a
+    reviewed change of ``tests/golden/rvv_sim_counts.json``.  Scalable
+    kernels retire >= 4x fewer instructions on rvv-1024 than on rvv-128,
+    the fixed-shape one does not budge, and no wider register ever
+    retires more."""
+    rows = _sim_counts(kernel)
+    assert rows == _golden("rvv_sim_counts.json")[kernel], \
+        f"{kernel} drifted from the golden; fresh rows: {rows}"
+    r = rows["rvv-128"]["executed"] / max(1, rows["rvv-1024"]["executed"])
+    if kernel in UNSCALABLE:
+        assert r <= 1.5, \
+            f"{kernel}: fixed-shape kernel's retired count moved with " \
+            f"VLEN ({r:.2f}x)"
+    else:
+        assert r >= 4.0, \
+            f"{kernel}: rvv-1024 retired only {r:.2f}x fewer " \
+            f"instructions than rvv-128 (want >= 4x)"
+    seq = [rows[t]["executed"] for t in SIM_SWEEP]
+    assert all(a >= b for a, b in zip(seq, seq[1:])), \
+        f"{kernel}: retired counts not monotone across {SIM_SWEEP}: {seq}"
 
 
 @pytest.mark.parametrize("kernel", sorted(CASES))
 def test_rvv_counts_pinned_to_committed_baselines(kernel):
     """What models RVV re-tiles at the RVV width, never at a chip's
     tile: on rvv-128 and rvv-1024, revec's factor and its retiled and
-    masked strip counts equal ``BENCH_port.json``'s, and the emitted
-    RVV's retired counts on the simulator (at the rvv_sim suite's
-    n = 1024, tail 1027) equal ``BENCH_rvv_sim.json``'s."""
-    port_rows = _committed("BENCH_port.json")[kernel]["targets"]
-    sim_rows = _committed("BENCH_rvv_sim.json")[kernel]["targets"]
-    case = next(c for c in harness.cases(n=1024, tail_n=1027)
-                if c.kernel == kernel)
+    masked strip counts equal ``tests/golden/port_counts.json``'s, and
+    the emitted RVV's retired counts on the simulator equal
+    ``tests/golden/rvv_sim_counts.json``'s."""
+    port_rows = _golden("port_counts.json")[kernel]
+    sim_rows = _golden("rvv_sim_counts.json")[kernel]
     k = _kernel(kernel)
-    args = case.make_args(np.random.default_rng(0))
     for target in ("rvv-128", "rvv-1024"):
         res = port.retile(k.fn, target)
         assert (res.factor, res.retiled, res.masked) == (
@@ -258,8 +308,42 @@ def test_rvv_counts_pinned_to_committed_baselines(kernel):
             port_rows[target]["retiled_strips"],
             port_rows[target]["masked_tails"]), target
         assert res.tiled == 0
-        _, counts = rvv.execute(rvv.emit(k, target), *args)
-        got = {"executed": counts["executed"], "vector": counts["vector"],
-               "vsetvli": counts["vsetvli"] + counts["implicit_vsetvli"],
-               "vuops": counts["vuops"]}
-        assert got == sim_rows[target], target
+        assert _sim_counts(kernel)[target] == sim_rows[target], target
+
+
+def _find_rvv_cc():
+    """A compiler on PATH that accepts ``-march=rv64gcv``, or None."""
+    import shutil
+    import subprocess
+    import tempfile
+    probes = [["clang", "--target=riscv64", "-march=rv64gcv"],
+              ["riscv64-linux-gnu-gcc", "-march=rv64gcv"],
+              ["riscv64-unknown-elf-gcc", "-march=rv64gcv"]]
+    for cc in probes:
+        if shutil.which(cc[0]) is None:
+            continue
+        with tempfile.NamedTemporaryFile("w", suffix=".c") as f:
+            f.write("#include <riscv_vector.h>\nint main(void)"
+                    "{return 0;}\n")
+            f.flush()
+            r = subprocess.run(cc + ["-fsyntax-only", f.name],
+                               capture_output=True)
+        if r.returncode == 0:
+            return cc
+    return None
+
+
+def test_emitted_units_pass_rv64gcv_syntax_check(tmp_path):
+    """Every emitted unit compiles under ``-fsyntax-only
+    -march=rv64gcv`` where such a compiler is on PATH."""
+    import subprocess
+    cc = _find_rvv_cc()
+    if cc is None:
+        pytest.skip("no RVV-capable compiler on PATH (probed clang "
+                    "--target=riscv64, riscv64-linux-gnu-gcc, "
+                    "riscv64-unknown-elf-gcc)")
+    for name in sorted(CASES):
+        for target in SIM_SWEEP:
+            path = tmp_path / f"{name}__{target}.c"
+            path.write_text(rvv.emit(_kernel(name), target).render_c())
+            subprocess.run(cc + ["-fsyntax-only", str(path)], check=True)

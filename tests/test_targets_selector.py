@@ -5,7 +5,10 @@ These tests only exercise selection/cost paths (select/explain/isa
 dispatch) — pallas kernel *execution* is covered elsewhere and needs TPU
 or interpret mode.
 """
+import functools
+import json
 import logging
+import os
 
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +16,12 @@ import pytest
 
 from repro.core import isa, targets, trace, use_policy, use_target
 from repro.core.registry import REGISTRY, Lowering, explain
+from repro.kernels import figure2
 from repro.kernels import ops  # noqa: F401  (registers kernel lowerings)
+
+with open(os.path.join(os.path.dirname(__file__), "golden",
+                       "figure2_counts.json")) as _f:
+    FIGURE2_GOLDEN = json.load(_f)
 
 
 # ---------------------------------------------------------------------------
@@ -293,9 +301,7 @@ def test_widening_ops_declare_output_width():
 def test_tpu_baseline_column_has_no_union_overhead():
     """The beyond-paper TPU baseline is the plain XLA jaxpr count — no
     SIMDe union round-trip (XLA fuses it away), no scalarized libm."""
-    from benchmarks import xnnpack_suite
-    rows = xnnpack_suite.run_tpu()
-    vrelu = next(r for r in rows if r["name"] == "vrelu")
+    vrelu = _figure2_column("tpu-v5e")["vrelu"]
     # jnp.clip on (1024,1024) fp32: 2 eqns x 1024 vregs, 1x (no union)
     assert vrelu["baseline_instrs"] == 2048
 
@@ -305,9 +311,60 @@ def test_figure2_ops_choose_customized_on_rvv128():
     lowering for the ten XNNPACK functions with baseline/customized > 1,
     vtanh/vsigmoid the largest (paper Figure-2 ordering); simple
     arithmetic keeps the vector tier."""
-    from benchmarks import xnnpack_suite
-    rows = xnnpack_suite.run_target("rvv-128", check=True)
-    assert len(rows) == len(xnnpack_suite.FIGURE2_OPS)
+    _assert_figure2_selection("rvv-128")
+    assert len(_figure2_column("rvv-128")) == len(figure2.FIGURE2_OPS)
+
+
+# ---------------------------------------------------------------------------
+# Figure 2 pinned: every column's counts and tiers, exactly
+# ---------------------------------------------------------------------------
+
+FIGURE2_COLUMNS = targets.RVV_FAMILY + ("tpu-v5e",)
+
+
+@functools.lru_cache(maxsize=None)
+def _figure2_column(target):
+    return {r["name"]: r for r in figure2.column(target)}
+
+
+def _assert_figure2_selection(target):
+    """The paper's Figure-2 selection properties on an RVV target."""
+    with use_target(target):
+        # Listing 8: the selector must KEEP the vector tier for simple
+        # arithmetic — a customized kernel cannot beat one vector op.
+        probe = jnp.zeros((1024,), jnp.float32)
+        arith = REGISTRY.explain("vadd", probe, probe, policy="pallas")
+    assert arith["chosen"] == "vector", arith
+    rows = _figure2_column(target)
+    ratio = {name: r["baseline_instrs"] / max(1, r["customized_instrs"])
+             for name, r in rows.items()}
+    for name in figure2.FIGURE2_OPS:
+        assert rows[name]["customized_tier"] == "pallas", \
+            f"{name}: selector kept {rows[name]['customized_tier']}, " \
+            f"not customized"
+        assert ratio[name] > 1.0, \
+            f"{name}: customized not cheaper ({ratio[name]:.2f}x)"
+    top2 = sorted(ratio, key=lambda n: -ratio[n])[:2]
+    assert set(top2) == {"vtanh", "vsigmoid"}, \
+        f"largest wins should be vtanh/vsigmoid, got {top2}"
+
+
+@pytest.mark.parametrize("target", targets.RVV_FAMILY)
+def test_figure2_selection_across_rvv_family(target):
+    _assert_figure2_selection(target)
+
+
+@pytest.mark.parametrize("op", figure2.FIGURE2_OPS)
+@pytest.mark.parametrize("target", FIGURE2_COLUMNS)
+def test_figure2_counts_match_golden(target, op):
+    """The counts are a deterministic cost-model evaluation, not a
+    measurement: any drift in a tier or an instruction count is a
+    reviewed change of ``tests/golden/figure2_counts.json``."""
+    row = _figure2_column(target)[op]
+    fresh = {k: row[k] for k in ("baseline_instrs", "customized_instrs",
+                                 "baseline_tier", "customized_tier")}
+    assert fresh == FIGURE2_GOLDEN[target][op], \
+        f"{op}@{target} drifted from the golden; fresh row: {fresh}"
 
 
 # ---------------------------------------------------------------------------

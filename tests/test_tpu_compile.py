@@ -26,9 +26,8 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from benchmarks import xnnpack_suite
 from repro.core.registry import REGISTRY
-from repro.kernels import flash_attention, ops, ssd
+from repro.kernels import figure2, flash_attention, ops, ssd
 
 CORPUS = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
                                       "examples", "neon_corpus"))
@@ -70,12 +69,12 @@ def _sds(x, sharding):
     return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("name", xnnpack_suite.FIGURE2_OPS)
+@pytest.mark.parametrize("name", figure2.FIGURE2_OPS)
 def test_xnnpack_kernel_compiles(name, one_chip, on_chip):
-    _, op, args, kw = next(w for w in xnnpack_suite.workloads()
+    _, op, args, kw = next(w for w in figure2.workloads()
                            if w[0] == name)
-    fn, arrs = xnnpack_suite.array_fn(REGISTRY.lowering(op, "pallas").fn,
-                                      args, kw)
+    fn, arrs = figure2.array_fn(REGISTRY.lowering(op, "pallas").fn,
+                                args, kw)
     _compile(fn, *[_sds(a, one_chip) for a in arrs])
 
 
